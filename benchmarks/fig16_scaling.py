@@ -5,11 +5,16 @@ Runs the shard_map trainer on 1/2/4/8 forged host devices in subprocesses
 core the wall-clock does not speed up — the reported metric is the
 *structural* one the dry-run validates at 256/512 chips: per-device token
 throughput normalized by shard count, plus token conservation.
+
+The children are structure checks on forged CPU devices by design: they
+run with ``JAX_PLATFORMS=cpu``, so on an accelerator host they never
+contend with the parent for the chip. A failed child fails the run.
 """
 
 from __future__ import annotations
 
 import json
+import os
 import subprocess
 import sys
 
@@ -51,10 +56,11 @@ def run():
     for n_dev in (1, 2, 4, 8):
         proc = subprocess.run(
             [sys.executable, "-c", _SCRIPT % (n_dev, n_dev)],
-            capture_output=True, text=True, timeout=600)
+            capture_output=True, text=True, timeout=600,
+            env={**os.environ, "JAX_PLATFORMS": "cpu"})
         if proc.returncode != 0:
-            rows.append((f"fig16/devices{n_dev}_error", 0.0, 1.0))
-            continue
+            raise RuntimeError(f"fig16 child on {n_dev} forged devices "
+                               f"failed:\n{proc.stderr[-4000:]}")
         r = json.loads(proc.stdout.strip().splitlines()[-1])
         assert r["conserved"]
         rows.append((f"fig16/devices{n_dev}_tokens_per_sec", 0.0,

@@ -26,6 +26,10 @@ before jax initializes):
 ``--dry-run`` shrinks everything to a seconds-long smoke (the CI hook)
 but still writes the same JSON schema.
 
+The children are structure checks on forged CPU devices by design: they
+run with ``JAX_PLATFORMS=cpu``, so on an accelerator host they never
+contend with the parent for the chip.
+
 Emits results/BENCH_ps_scaling.json (schema in docs/BENCHMARKS.md,
 gated by tools/check_bench.py).
 Run:  PYTHONPATH=src python benchmarks/ps_scaling.py [--dry-run]
@@ -116,7 +120,8 @@ def bench(out_path: str = "results/BENCH_ps_scaling.json",
     for n in worker_counts:
         arg = json.dumps({**params, "n_workers": n})
         proc = subprocess.run([sys.executable, "-c", _SCRIPT, arg],
-                              capture_output=True, text=True, timeout=1800)
+                              capture_output=True, text=True, timeout=1800,
+                              env={**os.environ, "JAX_PLATFORMS": "cpu"})
         if proc.returncode != 0:
             raise RuntimeError(
                 f"ps_scaling cell n_workers={n} failed:\n"

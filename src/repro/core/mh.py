@@ -102,16 +102,19 @@ def _onehot_prims(R: int, K: int):
     no 1D iota, so the Pallas TPU kernel can run the identical build.
     Values are bit-equal to the scatter primitives: a one-hot masked sum
     adds exact zeros, and a where-write stores the same f32/int32 value.
+    Per-row vectors are (R, 1) columns here, the shape Mosaic broadcasts
+    across lanes.
     """
     kk = jax.lax.broadcasted_iota(jnp.int32, (R, K), 1)
 
     def gather(arr, idx):
-        sel = kk == idx[:, None]
-        return jnp.sum(jnp.where(sel, arr, jnp.zeros_like(arr)), axis=1)
+        sel = kk == idx
+        return jnp.sum(jnp.where(sel, arr, jnp.zeros_like(arr)), axis=1,
+                       keepdims=True)
 
     def put(arr, idx, val, mask):
-        sel = (kk == idx[:, None]) & mask[:, None]
-        return jnp.where(sel, val[:, None].astype(arr.dtype), arr)
+        sel = (kk == idx) & mask
+        return jnp.where(sel, val.astype(arr.dtype), arr)
 
     return gather, put
 
@@ -148,12 +151,16 @@ def run_vose(scaled: jax.Array, squeue: jax.Array, lqueue: jax.Array,
     deterministic (same weights ⇒ bitwise-identical tables).
     """
     R, K = scaled.shape
-    prims = _onehot_prims(R, K) if onehot else _scatter_prims(R, K)
-    gather, put = prims
+    if onehot:
+        gather, put = _onehot_prims(R, K)
+        if n_small.ndim == 1:           # the kernel passes a column already
+            n_small = n_small[:, None]
+    else:
+        gather, put = _scatter_prims(R, K)
     n_large = (K - n_small).astype(jnp.int32)
     prob0 = jnp.ones((R, K), jnp.float32)
     alias0 = jax.lax.broadcasted_iota(jnp.int32, (R, K), 1)
-    zeros = jnp.zeros((R,), jnp.int32)
+    zeros = jnp.zeros_like(n_small, jnp.int32)
 
     def body(_, carry):
         scaled, squeue, s_head, s_tail, l_head, prob, alias = carry

@@ -54,7 +54,7 @@ __all__ = [
     "exact_three_branch", "exact_three_branch_tiled", "ThreeBranchStats",
     "sample",
     "build_plan", "Plan", "survivor_rank", "compact_survivor_indices",
-    "run_survivor_chunks",
+    "map_token_tiles", "run_survivor_chunks",
 ]
 
 
@@ -76,8 +76,14 @@ def word_stats(W_hat: jax.Array, *, g: int, alpha: float) -> WordStats:
     # The barrier stops XLA:CPU from fusing the top-k sort into each
     # consumer (which re-runs the sort per use — measured 30× slower).
     # Identity on values, so results are bit-identical.
+    n_top = min(g + 1, W_hat.shape[-1])
     vals, idxs = jax.lax.optimization_barrier(
-        jax.lax.top_k(W_hat, g + 1))                        # (V, g+1)
+        jax.lax.top_k(W_hat, n_top))                        # (V, n_top)
+    if n_top < g + 1:
+        # K ≤ g: no mass lies past the K real topics, so the missing
+        # a_i are 0 and Eq 10's tail term vanishes (S_est is then exact)
+        vals = jnp.pad(vals, ((0, 0), (0, g + 1 - n_top)))
+        idxs = jnp.pad(idxs, ((0, 0), (0, g + 1 - n_top)), mode="edge")
     wsum = jnp.sum(W_hat, axis=-1)                          # (V,)
     q_prime = alpha * (wsum - vals[:, 0])
     k = idxs[:, :g].astype(jnp.int32)
@@ -102,20 +108,29 @@ class SkipDecision(NamedTuple):
 def skip_phase(u: jax.Array, word_ids: jax.Array, doc_ids: jax.Array,
                D: jax.Array, stats: WordStats, *, g: int,
                alpha: float) -> SkipDecision:
-    """Eq 8-10 + the skip test. O(g) gathers per token, no O(K) work."""
-    a = stats.a[word_ids]                                   # (N, g+1)
-    ktop = stats.k[word_ids]                                # (N, g)
+    """Eq 8-10 + the skip test. O(g) gathers per token, no O(K) work.
+
+    Every per-token quantity is its own (N,) vector: an (N, g) array
+    would be padded to 128 lanes on a TPU, 64× its size at g = 2.
+    """
+    a = [stats.a[:, i][word_ids] for i in range(g + 1)]     # a_1..a_{g+1}
+    ktop = [stats.k[:, i][word_ids] for i in range(g)]      # K_1..K_g
     q_prime = stats.q_prime[word_ids]                       # (N,)
     len_d = jnp.sum(D, axis=-1, dtype=jnp.float32)[doc_ids] # (N,)
     # b_i = D[d][K_i], i = 1..g (g gathers per token)
-    b = D[doc_ids[:, None], ktop].astype(jnp.float32)       # (N, g)
-    m = a[:, 0] * (b[:, 0] + alpha)                         # Eq 8
+    b = [D[doc_ids, k].astype(jnp.float32) for k in ktop]
+    m = a[0] * (b[0] + alpha)                               # Eq 8
     # Eq 10: exact head terms (i = 2..g) + tail bound with a_{g+1}.
-    head = jnp.sum(a[:, 1:g] * b[:, 1:g], axis=-1)          # empty sum if g=1
-    tail = a[:, g] * (len_d - jnp.sum(b, axis=-1))
+    head = jnp.zeros_like(m)
+    for i in range(1, g):
+        head = head + a[i] * b[i]
+    b_sum = b[0]
+    for i in range(1, g):
+        b_sum = b_sum + b[i]
+    tail = a[g] * (len_d - b_sum)
     s_est = head + tail
     skip = u * (m + s_est + q_prime) < m
-    return SkipDecision(skip=skip, m=m, s_est=s_est, k1=ktop[:, 0])
+    return SkipDecision(skip=skip, m=m, s_est=s_est, k1=ktop[0])
 
 
 # ---------------------------------------------------------------------------
@@ -267,6 +282,24 @@ def survivor_rank(skip: jax.Array):
     n_surv = (rank[-1] + 1).astype(jnp.int32) if skip.shape[0] \
         else jnp.int32(0)
     return rank, n_surv
+
+
+def map_token_tiles(fn, idx, tile: int):
+    """``fn`` over consecutive ``tile``-slot slices of token indices
+    (``lax.map``), concatenated back to ``idx``'s length.
+
+    A chunk may hold every token of a corpus (survivor capacity starts at
+    N, and a data shard holds N / shards), so the (tokens, K) row gathers
+    a draw needs must not be made for all of it at once: this keeps one
+    tile's rows live. The tail tile is padded with index 0 and its
+    results are dropped. Draws are per token, so the tiling never changes
+    a value.
+    """
+    n = idx.shape[0]
+    tile = min(tile, n)
+    pad = (-n) % tile
+    out = jax.lax.map(fn, jnp.pad(idx, (0, pad)).reshape(-1, tile))
+    return jax.tree.map(lambda o: o.reshape(-1)[:n], out)
 
 
 def run_survivor_chunks(surv_idx, n_surv, init_topics, *, capacity,

@@ -26,6 +26,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.runtime import resolve_interpret
 
@@ -35,31 +36,30 @@ DEFAULT_TILE_T = 512
 DEFAULT_ROWS = 128
 
 
-def _kernel(row_ref, topic_ref, weight_ref, base_ref, out_ref, covered_ref,
+def _kernel(base_ref, row_ref, topic_ref, weight_ref, out_ref, covered_ref,
             *, rows_per_tile: int, block_k: int):
-    rows = row_ref[...]                                    # (T,) int32
-    topics = topic_ref[...]                                # (T,) int32
-    w = weight_ref[...]                                    # (T,) int32 mask
-    base = base_ref[0]
+    rows = row_ref[...]                                    # (1, T) int32
+    topics = topic_ref[...]                                # (T, 1) int32
+    w = weight_ref[...]                                    # (1, T) int32
+    base = base_ref[pl.program_id(0)]                      # SMEM scalar
     rel = rows - base
     in_win = jnp.logical_and(rel >= 0, rel < rows_per_tile)
+    use = jnp.logical_and(in_win, w > 0)                   # (1, T)
     kb = pl.program_id(1)
-    t_rel = topics - kb * block_k
-    in_kb = jnp.logical_and(t_rel >= 0, t_rel < block_k)
-    use = jnp.logical_and(in_win, jnp.logical_and(in_kb, w > 0))
-    # double one-hot (f32 for the MXU; counts are exact in f32 ≪ 2^24)
-    oh_r = (rel[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (rows.shape[0], rows_per_tile), 1))
-    oh_k = (t_rel[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (topics.shape[0], block_k), 1))
-    oh_r = jnp.where(use[:, None], oh_r, False).astype(jnp.float32)
-    out_ref[0] = jax.lax.dot_general(
-        oh_r, oh_k.astype(jnp.float32),
-        (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(jnp.int32)
-    # tokens this tile could NOT cover (row outside window): per-k-block the
-    # same set, so emit once (kb 0) for the caller's fallback scatter.
-    covered_ref[...] = jnp.logical_and(in_win, w > 0)
+    t_rel = topics - kb * block_k                          # (T, 1)
+    n_tok = topics.shape[0]
+    # double one-hot (f32 for the MXU; counts are exact in f32 ≪ 2^24).
+    # The row one-hot is built transposed, (R, T), so the matmul contracts
+    # its minor axis; a topic outside this k block matches no lane.
+    oh_r = jnp.logical_and(rel == jax.lax.broadcasted_iota(
+        jnp.int32, (rows_per_tile, n_tok), 0), use).astype(jnp.float32)
+    oh_k = (t_rel == jax.lax.broadcasted_iota(
+        jnp.int32, (n_tok, block_k), 1)).astype(jnp.float32)
+    out_ref[0] = jax.lax.dot(
+        oh_r, oh_k, preferred_element_type=jnp.float32).astype(jnp.int32)
+    # tokens this tile could NOT cover (row outside window) are the
+    # caller's fallback scatter; the set is the same for every k block.
+    covered_ref[...] = use.astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -72,30 +72,33 @@ def histogram_partials(row_ids: jax.Array, topics: jax.Array,
     """Per-tile (R×K) one-hot MXU partial histograms + coverage mask."""
     interpret = resolve_interpret(interpret)
     n = row_ids.shape[0]
-    assert n % tile_t == 0, "pad tokens to a tile multiple first"
+    if n % tile_t:
+        raise ValueError(f"{n} tokens are not a multiple of tile_t={tile_t}:"
+                         " pad the token list to whole tiles first")
     n_tiles = n // tile_t
     block_k = min(block_k, n_topics)
     k_pad = (-n_topics) % block_k
     n_kblocks = (n_topics + k_pad) // block_k
-    tok = pl.BlockSpec((tile_t,), lambda t, kb: (t,))
-    base_spec = pl.BlockSpec((1,), lambda t, kb: (t,))
+    # the tile bases ride in as scalar prefetch (SMEM), one per tile
+    row_spec = pl.BlockSpec((1, tile_t), lambda t, kb, bases: (0, t))
+    col_spec = pl.BlockSpec((tile_t, 1), lambda t, kb, bases: (t, 0))
     out_spec = pl.BlockSpec((1, rows_per_tile, block_k),
-                            lambda t, kb: (t, 0, kb))
-    cov_spec = pl.BlockSpec((tile_t,), lambda t, kb: (t,))
+                            lambda t, kb, bases: (t, 0, kb))
     partials, covered = pl.pallas_call(
         functools.partial(_kernel, rows_per_tile=rows_per_tile,
                           block_k=block_k),
-        grid=(n_tiles, n_kblocks),
-        in_specs=[tok, tok, tok, base_spec],
-        out_specs=(out_spec, cov_spec),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(n_tiles, n_kblocks),
+            in_specs=[row_spec, col_spec, row_spec],
+            out_specs=(out_spec, row_spec)),
         out_shape=(
             jax.ShapeDtypeStruct((n_tiles, rows_per_tile,
                                   n_kblocks * block_k), jnp.int32),
-            jax.ShapeDtypeStruct((n,), jnp.bool_),
+            jax.ShapeDtypeStruct((1, n), jnp.int32),
         ),
         interpret=interpret,
-    )(row_ids, topics, weights, tile_bases)
-    return partials[:, :, :n_topics], covered
+    )(tile_bases, row_ids[None], topics[:, None], weights[None])
+    return partials[:, :, :n_topics], covered[0] != 0
 
 
 def histogram(row_ids: jax.Array, topics: jax.Array, weights: jax.Array, *,

@@ -106,7 +106,9 @@ def sparse_tail_draw(u, packed_rows, w_rows, k1, a1, b1, q_prime, *,
     in_m).
     """
     idx = (packed_rows.view(jnp.uint32) >> 16).astype(jnp.int32)
-    w_at = jnp.take_along_axis(w_rows, idx, axis=1)
+    # an empty slot (EMPTY_IDX, 0) reads a clipped column: its mass is 0
+    # either way, where the default fill would make it 0 * NaN
+    w_at = jnp.take_along_axis(w_rows, idx, axis=1, mode="clip")
     topics, needs_q, s_prime = _sparse.sample_sparse(
         u, packed_rows, w_at, k1, a1, b1, q_prime, alpha=alpha,
         interpret=interpret)
@@ -137,7 +139,8 @@ def sparse_tail_draw_tiled(u, packed_rows, w_hat, word_ids, first_word,
     topics, needs_q, s_prime = _sparse.sample_sparse_tiled(
         u, packed_rows, jnp.take_along_axis(
             rows,
-            (packed_rows.view(jnp.uint32) >> 16).astype(jnp.int32), axis=1),
+            (packed_rows.view(jnp.uint32) >> 16).astype(jnp.int32), axis=1,
+            mode="clip"),
         word_ids, first, k1_w, a1_w, q_prime_w, b1, alpha=alpha,
         win_words=win_words, interpret=interpret)
     k1_win = jax.lax.dynamic_slice(k1_w, (first,), (win,))

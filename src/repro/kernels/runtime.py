@@ -13,10 +13,23 @@ __all__ = ["interpret_default", "resolve_interpret"]
 
 
 def interpret_default() -> bool:
-    """Interpret on anything that is not a real TPU."""
-    return jax.default_backend() != "tpu"
+    """Compile to Mosaic on a TPU, interpret on the CPU, refuse elsewhere.
+
+    The CPU is where the tests run the kernels; any other backend (a GPU,
+    or a host whose chip went missing and fell back to something else)
+    raises instead of quietly running the slow interpreter.
+    """
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"the Pallas kernels compile only for a TPU (and run interpreted "
+        f"on the CPU for tests); the default backend is {backend!r}. Use "
+        "impl='xla' here, or pass interpret=True explicitly")
 
 
 def resolve_interpret(interpret: bool | None) -> bool:
-    """``None`` means "compile to Mosaic iff we are on a TPU"."""
+    """``None`` means "compile on a TPU, interpret on the CPU"."""
     return interpret_default() if interpret is None else bool(interpret)

@@ -55,14 +55,37 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.runtime import resolve_interpret
-from repro.runtime.compat import tpu_compiler_params
 
-__all__ = ["sample_fused", "sample_fused_tiled",
-           "DEFAULT_TILE_T", "DEFAULT_BLOCK_K"]
+__all__ = ["sample_fused", "sample_fused_tiled", "lane_prefix_sum",
+           "first_lane", "window_rows", "DEFAULT_TILE_T", "DEFAULT_BLOCK_K"]
 
 DEFAULT_TILE_T = 128
 DEFAULT_BLOCK_K = 512
 _NEG_INF = -1e30  # python float: jnp module-level consts can't be captured
+
+
+def lane_prefix_sum(x):
+    """Inclusive prefix sum along the lane (last) axis of a 2-D block.
+
+    Mosaic has no ``cumsum``; this is the log-step (Hillis–Steele) scan:
+    ceil(log2(width)) lane rotations on the XLU plus masked adds on the
+    VPU. Shared by the fused and sparse kernels so both accumulate their
+    CDFs in the same order.
+    """
+    width = x.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    shift = 1
+    while shift < width:
+        x = x + jnp.where(lane >= shift, pltpu.roll(x, shift, 1),
+                          jnp.zeros_like(x))
+        shift *= 2
+    return x
+
+
+def first_lane(hit):
+    """Index of the first true lane per row (``width`` when none is)."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, hit.shape, 1)
+    return jnp.min(jnp.where(hit, lane, hit.shape[1]), axis=1, keepdims=True)
 
 
 def _phase_body(phase, kb, d, w, valid,                 # per-block values
@@ -74,10 +97,11 @@ def _phase_body(phase, kb, d, w, valid,                 # per-block values
     ``d``/``w`` are the resolved (T, BK) blocks — pre-gathered rows for the
     plain kernel, window-resolved rows for the tiled kernel. Everything
     downstream is identical, which is what makes the two entry points
-    bit-equal.
+    bit-equal. Per-token state lives in (T, 1) columns: Mosaic broadcasts
+    a column across lanes, but cannot reshape a 1-D vector into one.
     """
-    k_global = kb * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, d.shape, dimension=1)               # (T, BK)
+    lane = jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
+    k_global = kb * block_k + lane                     # (T, BK)
 
     @pl.when((phase == 0) & (kb == 0))
     def _init():
@@ -90,18 +114,17 @@ def _phase_body(phase, kb, d, w, valid,                 # per-block values
     @pl.when(phase == 0)
     def _masses():
         wv = jnp.where(valid, w, _NEG_INF)
-        blk_max = jnp.max(wv, axis=1)                  # (T,)
-        blk_arg = jnp.argmax(wv, axis=1).astype(jnp.int32)
-        sel = blk_arg[:, None] == jax.lax.broadcasted_iota(
-            jnp.int32, d.shape, 1)
-        blk_d = jnp.sum(jnp.where(sel, d, 0.0), axis=1)
+        blk_max = jnp.max(wv, axis=1, keepdims=True)   # (T, 1)
+        blk_arg = first_lane(wv == blk_max)            # argmax, first tie
+        blk_d = jnp.sum(jnp.where(lane == blk_arg, d, 0.0), axis=1,
+                        keepdims=True)
         better = blk_max > amax[...]
         amax[...] = jnp.where(better, blk_max, amax[...])
         kmax[...] = jnp.where(better, kb * block_k + blk_arg, kmax[...])
         bmax[...] = jnp.where(better, blk_d, bmax[...])
         wz = jnp.where(valid, w, 0.0)
-        sum_s[...] += jnp.sum(d * wz, axis=1)
-        sum_q[...] += jnp.sum(wz, axis=1)
+        sum_s[...] += jnp.sum(d * wz, axis=1, keepdims=True)
+        sum_q[...] += jnp.sum(wz, axis=1, keepdims=True)
 
     @pl.when((phase == 1) & (kb == 0))
     def _finalize_masses():
@@ -115,29 +138,32 @@ def _phase_body(phase, kb, d, w, valid,                 # per-block values
         q_ref[...] = q_p
         x = u_ref[...] * (m + s_p + q_p)
         target[...] = x - m                            # combined-CDF target
-        found[...] = x < m                             # M branch ⇒ K1
+        found[...] = (x < m).astype(jnp.int32)         # M branch ⇒ K1
         cand[...] = kmax[...]
         cum[...] = jnp.zeros_like(cum[...])
 
     @pl.when(phase == 1)
     def _cdf():
         mass = (d + alpha) * w
-        mass = jnp.where(valid & (k_global != kmax[...][:, None]), mass, 0.0)
-        c = cum[...][:, None] + jnp.cumsum(mass, axis=1)   # (T, BK)
-        hit = c > target[...][:, None]
-        any_hit = jnp.any(hit, axis=1)
-        # first hit: cumsum is monotone per row, so argmax finds it
-        first = jnp.argmax(hit, axis=1).astype(jnp.int32)
-        take = jnp.logical_and(jnp.logical_not(found[...]), any_hit)
+        mass = jnp.where(valid & (k_global != kmax[...]), mass, 0.0)
+        c = cum[...] + lane_prefix_sum(mass)           # (T, BK)
+        # only a lane with mass may be drawn: the log-step scan is not
+        # monotone to the last ulp, and a padding lane is not a topic
+        hit = (c > target[...]) & (mass > 0)
+        first = first_lane(hit)
+        any_hit = first < block_k
+        take = (found[...] == 0) & any_hit
         cand[...] = jnp.where(take, kb * block_k + first, cand[...])
-        found[...] = jnp.logical_or(found[...], any_hit)
-        cum[...] = c[:, -1]
+        found[...] = jnp.where(any_hit, 1, found[...])
+        cum[...] = jnp.sum(jnp.where(lane == block_k - 1, c, 0.0), axis=1,
+                           keepdims=True)
 
         @pl.when(kb == n_kblocks - 1)
         def _emit():
             # numerical tail guard: u ≈ 1 with float cumsum undershoot —
             # clamp to the last valid topic (measure-zero event)
-            topic_ref[...] = jnp.where(found[...], cand[...], k_total - 1)
+            topic_ref[...] = jnp.where(found[...] != 0, cand[...],
+                                       k_total - 1)
 
 
 def _kernel(u_ref, d_ref, w_ref,                       # inputs
@@ -158,6 +184,20 @@ def _kernel(u_ref, d_ref, w_ref,                       # inputs
                 alpha=alpha)
 
 
+def window_rows(local, window):
+    """Rows ``window[local[t]]`` for a (T, 1) column of window offsets.
+
+    Mosaic has no row gather, so this is a one-hot (T, win) @ (win, BK)
+    matmul on the MXU. Every output is one exact product plus exact
+    zeros, and HIGHEST precision keeps the f32 values whole, so the rows
+    are bit-equal to a gather.
+    """
+    onehot = (local == jax.lax.broadcasted_iota(
+        jnp.int32, (local.shape[0], window.shape[0]), 1)).astype(window.dtype)
+    return jax.lax.dot(onehot, window, precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=window.dtype)
+
+
 def _tiled_kernel(u_ref, local_ref, d_ref, wwin_ref,   # inputs
                   topic_ref, m_ref, s_ref, q_ref,      # outputs
                   amax, bmax, kmax, sum_s, sum_q, cum, target, found, cand,
@@ -167,9 +207,8 @@ def _tiled_kernel(u_ref, local_ref, d_ref, wwin_ref,   # inputs
     kb = pl.program_id(2)
     d = d_ref[...].astype(jnp.float32)                 # (T, BK)
     # resolve each token's Ŵ row from the tile's resident word window —
-    # the two-level (word, region) lookup. jnp.take keeps interpret mode
-    # and Mosaic's dynamic-gather lowering on the same path.
-    w = jnp.take(wwin_ref[...], local_ref[...], axis=0)  # (T, BK)
+    # the two-level (word, region) lookup
+    w = window_rows(local_ref[...], wwin_ref[...])     # (T, BK)
     k_global = kb * block_k + jax.lax.broadcasted_iota(
         jnp.int32, d.shape, dimension=1)
     valid = k_global < k_total
@@ -181,20 +220,22 @@ def _tiled_kernel(u_ref, local_ref, d_ref, wwin_ref,   # inputs
 
 
 def _scratch(tile_t: int):
-    return [pltpu.VMEM((tile_t,), jnp.float32)] * 2 \
-        + [pltpu.VMEM((tile_t,), jnp.int32)] \
-        + [pltpu.VMEM((tile_t,), jnp.float32)] * 4 \
-        + [pltpu.VMEM((tile_t,), jnp.bool_)] \
-        + [pltpu.VMEM((tile_t,), jnp.int32)]
+    col = lambda dt: pltpu.VMEM((tile_t, 1), dt)  # noqa: E731
+    return [col(jnp.float32)] * 2 + [col(jnp.int32)] \
+        + [col(jnp.float32)] * 4 + [col(jnp.int32)] * 2
 
 
 def _out_shapes(n: int):
     return (
-        jax.ShapeDtypeStruct((n,), jnp.int32),    # topic
-        jax.ShapeDtypeStruct((n,), jnp.float32),  # M
-        jax.ShapeDtypeStruct((n,), jnp.float32),  # S'
-        jax.ShapeDtypeStruct((n,), jnp.float32),  # Q'
+        jax.ShapeDtypeStruct((n, 1), jnp.int32),    # topic
+        jax.ShapeDtypeStruct((n, 1), jnp.float32),  # M
+        jax.ShapeDtypeStruct((n, 1), jnp.float32),  # S'
+        jax.ShapeDtypeStruct((n, 1), jnp.float32),  # Q'
     )
+
+
+def _columns(outs, n: int):
+    return tuple(o[:n, 0] for o in outs)
 
 
 @functools.partial(jax.jit,
@@ -229,20 +270,20 @@ def sample_fused(u: jax.Array, d_rows: jax.Array, w_rows: jax.Array, *,
     kernel = functools.partial(
         _kernel, block_k=block_k, n_kblocks=n_kblocks, k_total=k_total,
         alpha=float(alpha))
-    tok_spec = pl.BlockSpec((tile_t,), lambda t, p, kb: (t,))
+    tok_spec = pl.BlockSpec((tile_t, 1), lambda t, p, kb: (t, 0))
     mat_spec = pl.BlockSpec((tile_t, block_k), lambda t, p, kb: (t, kb))
-    topics, m, s, q = pl.pallas_call(
+    outs = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[tok_spec, mat_spec, mat_spec],
         out_specs=(tok_spec, tok_spec, tok_spec, tok_spec),
         out_shape=_out_shapes(n_tiles * tile_t),
         scratch_shapes=_scratch(tile_t),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
-    )(u, d_rows, w_rows)
-    return topics[:n], m[:n], s[:n], q[:n]
+    )(u[:, None], d_rows, w_rows)
+    return _columns(outs, n)
 
 
 @functools.partial(jax.jit,
@@ -290,7 +331,9 @@ def sample_fused_tiled(u: jax.Array, d_rows: jax.Array, w_hat: jax.Array,
         u = jnp.pad(u, (0, n_pad))
         local = jnp.pad(local, (0, n_pad))
         d_rows = jnp.pad(d_rows, ((0, n_pad), (0, k_pad)))
-        window = jnp.pad(window, ((0, 0), (0, k_pad)))
+    # lane-align the window's rows: they are the one-hot matmul's
+    # contraction axis (padding rows are never selected)
+    window = jnp.pad(window, ((0, (-win) % 128), (0, k_pad)))
     n_tiles = u.shape[0] // tile_t
     n_kblocks = window.shape[1] // block_k
 
@@ -298,18 +341,19 @@ def sample_fused_tiled(u: jax.Array, d_rows: jax.Array, w_hat: jax.Array,
     kernel = functools.partial(
         _tiled_kernel, block_k=block_k, n_kblocks=n_kblocks,
         k_total=k_total, alpha=float(alpha))
-    tok_spec = pl.BlockSpec((tile_t,), lambda t, p, kb: (t,))
+    tok_spec = pl.BlockSpec((tile_t, 1), lambda t, p, kb: (t, 0))
     mat_spec = pl.BlockSpec((tile_t, block_k), lambda t, p, kb: (t, kb))
-    win_spec = pl.BlockSpec((win, block_k), lambda t, p, kb: (0, kb))
-    topics, m, s, q = pl.pallas_call(
+    win_spec = pl.BlockSpec((window.shape[0], block_k),
+                            lambda t, p, kb: (0, kb))
+    outs = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[tok_spec, tok_spec, mat_spec, win_spec],
         out_specs=(tok_spec, tok_spec, tok_spec, tok_spec),
         out_shape=_out_shapes(n_tiles * tile_t),
         scratch_shapes=_scratch(tile_t),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
-    )(u, local, d_rows, window)
-    return topics[:n], m[:n], s[:n], q[:n]
+    )(u[:, None], local[:, None], d_rows, window)
+    return _columns(outs, n)

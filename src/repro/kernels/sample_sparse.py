@@ -32,9 +32,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.runtime import resolve_interpret
+from repro.kernels.sample_fused import first_lane, lane_prefix_sum
 
 __all__ = ["sample_sparse", "sample_sparse_tiled"]
 
@@ -43,29 +43,34 @@ DEFAULT_TILE_T = 256
 
 def _draw(u, packed, w_at, k1, a1, b1, qp,
           topic_ref, needs_q_ref, s_ref, *, alpha: float):
-    """Shared O(L) three-branch draw body (plain and tiled kernels)."""
-    # 16/16 pair unpack (paper §IV-B) — unsigned shift via uint32 view
-    up = pltpu.bitcast(packed, jnp.uint32)
-    idx = (up >> 16).astype(jnp.int32)
-    val = (up & 0xFFFF).astype(jnp.float32)
+    """Shared O(L) three-branch draw body (plain and tiled kernels).
+
+    Per-token values are (T, 1) columns; slot arrays are (T, L).
+    """
+    # 16/16 pair unpack (paper §IV-B) in int32: shift, then mask the sign
+    # extension away (Mosaic has no unsigned-to-float cast)
+    idx = (packed >> 16) & 0xFFFF
+    val = (packed & 0xFFFF).astype(jnp.float32)
     m = a1 * (b1 + alpha)                                 # Eq 8
-    w_eff = jnp.where(idx == k1[:, None], 0.0, w_at)      # zero the K1 slot
+    w_eff = jnp.where(idx == k1, 0.0, w_at)               # zero the K1 slot
     p_s = val * w_eff
-    cdf = jnp.cumsum(p_s, axis=1)
-    s_p = cdf[:, -1]
+    cdf = lane_prefix_sum(p_s)
+    lane = jax.lax.broadcasted_iota(jnp.int32, idx.shape, 1)
+    s_p = jnp.sum(jnp.where(lane == idx.shape[1] - 1, cdf, 0.0), axis=1,
+                  keepdims=True)
     x = u * (m + s_p + qp)
     in_m = x < m
-    hit = cdf > (x - m)[:, None]
-    found = jnp.any(hit, axis=1)
-    slot = jnp.argmax(hit, axis=1)[:, None]
-    rows_sel = jnp.sum(jnp.where(
-        jax.lax.broadcasted_iota(jnp.int32, idx.shape, 1) == slot, idx, 0),
-        axis=1)
+    # only a slot with mass may be drawn: the log-step scan is not monotone
+    # to the last ulp, and an empty slot's idx is not a topic
+    slot = first_lane((cdf > (x - m)) & (p_s > 0))
+    found = slot < idx.shape[1]
+    rows_sel = jnp.sum(jnp.where(lane == slot, idx, 0), axis=1,
+                       keepdims=True)
     in_s = jnp.logical_and(jnp.logical_not(in_m),
                            jnp.logical_and(found, x < m + s_p))
     needs_q = jnp.logical_and(jnp.logical_not(in_m), jnp.logical_not(in_s))
     topic_ref[...] = jnp.where(in_m, k1, jnp.where(in_s, rows_sel, -1))
-    needs_q_ref[...] = needs_q
+    needs_q_ref[...] = needs_q.astype(jnp.int32)
     s_ref[...] = s_p
 
 
@@ -76,24 +81,45 @@ def _kernel(u_ref, packed_ref, w_ref, k1_ref, a1_ref, b1_ref, qp_ref,
           alpha=alpha)
 
 
+def _window_lookup(local, row):
+    """``row[0, local[t]]`` for a (T, 1) column of window offsets: a
+    one-hot select-and-sum over the (1, win) row (exact: one value plus
+    zeros), since Mosaic gathers only along 2-D rows."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (local.shape[0],
+                                                row.shape[1]), 1)
+    return jnp.sum(jnp.where(lane == local, row, jnp.zeros_like(row)),
+                   axis=1, keepdims=True)
+
+
 def _tiled_kernel(u_ref, packed_ref, w_ref, local_ref, b1_ref,
                   k1w_ref, a1w_ref, qpw_ref,
                   topic_ref, needs_q_ref, s_ref, *, alpha: float):
     # per-word stats resolved from the tile's word window (two-level index)
     local = local_ref[...]
-    k1 = jnp.take(k1w_ref[...], local)
-    a1 = jnp.take(a1w_ref[...], local)
-    qp = jnp.take(qpw_ref[...], local)
+    k1 = _window_lookup(local, k1w_ref[...])
+    a1 = _window_lookup(local, a1w_ref[...])
+    qp = _window_lookup(local, qpw_ref[...])
     _draw(u_ref[...], packed_ref[...], w_ref[...], k1, a1, b1_ref[...], qp,
           topic_ref, needs_q_ref, s_ref, alpha=alpha)
 
 
 def _out_shapes(n: int):
     return (
-        jax.ShapeDtypeStruct((n,), jnp.int32),
-        jax.ShapeDtypeStruct((n,), jnp.bool_),
-        jax.ShapeDtypeStruct((n,), jnp.float32),
+        jax.ShapeDtypeStruct((n, 1), jnp.int32),
+        jax.ShapeDtypeStruct((n, 1), jnp.int32),
+        jax.ShapeDtypeStruct((n, 1), jnp.float32),
     )
+
+
+def _lane_pad(rows, n_pad: int):
+    """Pad (N, L) slot rows to a tile multiple of tokens and a lane-aligned
+    slot count; the extra slots are (idx 0, val 0) and carry no mass."""
+    return jnp.pad(rows, ((0, n_pad), (0, (-rows.shape[1]) % 128)))
+
+
+def _unpack_outs(outs, n: int):
+    topics, needs_q, s_p = (o[:n, 0] for o in outs)
+    return topics, needs_q != 0, s_p
 
 
 @functools.partial(jax.jit, static_argnames=("alpha", "tile_t", "interpret"))
@@ -112,28 +138,25 @@ def sample_sparse(u: jax.Array, packed_rows: jax.Array, w_at_idx: jax.Array,
       (topics, needs_q, s_prime); topics = -1 where needs_q.
     """
     interpret = resolve_interpret(interpret)
-    n, L = packed_rows.shape
+    n = packed_rows.shape[0]
     n_pad = (-n) % tile_t
-    if n_pad:
-        u = jnp.pad(u, (0, n_pad))
-        packed_rows = jnp.pad(packed_rows, ((0, n_pad), (0, 0)))
-        w_at_idx = jnp.pad(w_at_idx, ((0, n_pad), (0, 0)))
-        k1 = jnp.pad(k1, (0, n_pad))
-        a1 = jnp.pad(a1, (0, n_pad), constant_values=1.0)
-        b1 = jnp.pad(b1, (0, n_pad))
-        q_prime = jnp.pad(q_prime, (0, n_pad))
-    n_tiles = u.shape[0] // tile_t
-    tok = pl.BlockSpec((tile_t,), lambda t: (t,))
-    mat = pl.BlockSpec((tile_t, L), lambda t: (t, 0))
-    topics, needs_q, s_p = pl.pallas_call(
+    col = lambda a, fill=0: jnp.pad(a, (0, n_pad),  # noqa: E731
+                                    constant_values=fill)[:, None]
+    packed_rows = _lane_pad(packed_rows, n_pad)
+    w_at_idx = _lane_pad(w_at_idx, n_pad)
+    n_tiles = (n + n_pad) // tile_t
+    tok = pl.BlockSpec((tile_t, 1), lambda t: (t, 0))
+    mat = pl.BlockSpec((tile_t, packed_rows.shape[1]), lambda t: (t, 0))
+    outs = pl.pallas_call(
         functools.partial(_kernel, alpha=float(alpha)),
         grid=(n_tiles,),
         in_specs=[tok, mat, mat, tok, tok, tok, tok],
         out_specs=(tok, tok, tok),
         out_shape=_out_shapes(n_tiles * tile_t),
         interpret=interpret,
-    )(u, packed_rows, w_at_idx, k1, a1, b1, q_prime)
-    return topics[:n], needs_q[:n], s_p[:n]
+    )(col(u), packed_rows, w_at_idx, col(k1), col(a1, 1.0), col(b1),
+      col(q_prime))
+    return _unpack_outs(outs, n)
 
 
 @functools.partial(jax.jit,
@@ -159,31 +182,31 @@ def sample_sparse_tiled(u: jax.Array, packed_rows: jax.Array,
       per-token gathered stats.
     """
     interpret = resolve_interpret(interpret)
-    n, L = packed_rows.shape
+    n = packed_rows.shape[0]
     v_total = k1_w.shape[0]
     win = int(min(win_words, v_total))
     first = jnp.clip(jnp.asarray(first_word, jnp.int32), 0, v_total - win)
-    k1_win = jax.lax.dynamic_slice(k1_w, (first,), (win,))
-    a1_win = jax.lax.dynamic_slice(a1_w, (first,), (win,))
-    qp_win = jax.lax.dynamic_slice(q_prime_w, (first,), (win,))
+    # each window as one lane-aligned (1, win) row; padding lanes are
+    # never selected
+    row = lambda a: jnp.pad(  # noqa: E731
+        jax.lax.dynamic_slice(a, (first,), (win,)), (0, (-win) % 128))[None]
+    k1_win, a1_win, qp_win = row(k1_w), row(a1_w), row(q_prime_w)
     local = jnp.clip(word_ids.astype(jnp.int32) - first, 0, win - 1)
     n_pad = (-n) % tile_t
-    if n_pad:
-        u = jnp.pad(u, (0, n_pad))
-        packed_rows = jnp.pad(packed_rows, ((0, n_pad), (0, 0)))
-        w_at_idx = jnp.pad(w_at_idx, ((0, n_pad), (0, 0)))
-        local = jnp.pad(local, (0, n_pad))
-        b1 = jnp.pad(b1, (0, n_pad))
-    n_tiles = u.shape[0] // tile_t
-    tok = pl.BlockSpec((tile_t,), lambda t: (t,))
-    mat = pl.BlockSpec((tile_t, L), lambda t: (t, 0))
-    win_spec = pl.BlockSpec((win,), lambda t: (0,))
-    topics, needs_q, s_p = pl.pallas_call(
+    col = lambda a: jnp.pad(a, (0, n_pad))[:, None]  # noqa: E731
+    packed_rows = _lane_pad(packed_rows, n_pad)
+    w_at_idx = _lane_pad(w_at_idx, n_pad)
+    n_tiles = (n + n_pad) // tile_t
+    tok = pl.BlockSpec((tile_t, 1), lambda t: (t, 0))
+    mat = pl.BlockSpec((tile_t, packed_rows.shape[1]), lambda t: (t, 0))
+    win_spec = pl.BlockSpec((1, k1_win.shape[1]), lambda t: (0, 0))
+    outs = pl.pallas_call(
         functools.partial(_tiled_kernel, alpha=float(alpha)),
         grid=(n_tiles,),
         in_specs=[tok, mat, mat, tok, tok, win_spec, win_spec, win_spec],
         out_specs=(tok, tok, tok),
         out_shape=_out_shapes(n_tiles * tile_t),
         interpret=interpret,
-    )(u, packed_rows, w_at_idx, local, b1, k1_win, a1_win, qp_win)
-    return topics[:n], needs_q[:n], s_p[:n]
+    )(col(u), packed_rows, w_at_idx, col(local), col(b1), k1_win, a1_win,
+      qp_win)
+    return _unpack_outs(outs, n)

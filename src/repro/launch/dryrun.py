@@ -38,7 +38,6 @@ from repro.configs import REGISTRY, SHAPES, shape_applicable
 from repro.launch.mesh import make_production_mesh
 from repro.models.registry import get_model, input_specs
 from repro.roofline.analysis import HW, roofline_terms, summarize_memory
-from repro.runtime.compat import shard_map as _compat_shard_map
 from repro.runtime.sharding import batch_axes, safe_spec
 from repro.train import partition
 from repro.train.serve_step import (make_prefill_step, make_serve_step,
@@ -207,7 +206,7 @@ def lower_lda(multi_pod: bool, n_topics: int = 1024, v: int = 65_536,
     step = functools.partial(
         _dist_step, cfg=cfg, data_axes=daxes, model_axis="model",
         n_words=v, m_local=m_loc, g=cfg.g)
-    smapped = _compat_shard_map(
+    smapped = jax.shard_map(
         step, mesh=mesh,
         in_specs=(tok_spec, tok_spec, tok_spec, state_specs),
         out_specs=(state_specs, stats_spec), check_vma=False)

@@ -146,6 +146,8 @@ def main(argv=None) -> int:
     ap.add_argument("--lda-export", default=None, metavar="PATH",
                     help="write the FrozenLDAModel serving artifact here")
     args = ap.parse_args(argv)
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.lda:
         hist = train_lda(n_topics=args.lda_topics, iters=args.lda_iters,
                          n_docs=args.lda_docs, n_words=args.lda_words,

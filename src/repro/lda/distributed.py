@@ -51,6 +51,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map as _shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import balance as balance_mod
@@ -59,7 +60,6 @@ from repro.lda import invariants
 from repro.lda.corpus import Corpus, chunk_documents
 from repro.lda.model import HybridLayout, LDAConfig
 from repro.runtime import chaos
-from repro.runtime.compat import shard_map as _shard_map
 from repro.runtime.sharding import batch_axes
 
 __all__ = ["ShardedCorpus", "shard_corpus", "DistLDAState",
@@ -304,16 +304,34 @@ def _word_phase(W, *, cfg: LDAConfig, model_axis: str, n_words: int,
     return W_hat, g_vals, g_idx, q_prime_w
 
 
-def _token_sweep(u, word_ids, doc_ids, d_tok, len_tot, W_hat, g_vals,
+def _token_sweep(u, word_ids, doc_ids, d_rows, len_tot, W_hat, g_vals,
                  g_idx, q_prime_w, *, alpha: float, g: int, kb0,
-                 k_local: int, my, model_axis: str):
+                 k_local: int, my, model_axis: str, tile: int):
     """Skip phase + combined-sweep phase 2 for one batch of tokens.
 
     Per-token work only (gathers against the epoch/iteration-start
     counts and word stats), so the streamed path can run it per token
     sub-shard and the resident path over the whole slice — identical
-    per-token results. Returns (new_topics, skip, in_m, k1).
+    per-token results. ``d_rows(doc_ids)`` gives the tokens' (·, K_loc)
+    D rows. The sweep runs one ``tile`` of tokens at a time: it holds
+    (tile, K_loc) rows, and a whole shard's would not fit a chip.
+    Returns (new_topics, skip, in_m, k1).
     """
+    def sweep(idx):
+        d_t = doc_ids[idx]
+        return _tile_sweep(u[idx], word_ids[idx], d_t, d_rows(d_t), len_tot,
+                           W_hat, g_vals, g_idx, q_prime_w, alpha=alpha,
+                           g=g, kb0=kb0, k_local=k_local, my=my,
+                           model_axis=model_axis)
+
+    return three_branch.map_token_tiles(
+        sweep, jnp.arange(u.shape[0], dtype=jnp.int32), tile)
+
+
+def _tile_sweep(u, word_ids, doc_ids, d_tok, len_tot, W_hat, g_vals, g_idx,
+                q_prime_w, *, alpha: float, g: int, kb0, k_local: int, my,
+                model_axis: str):
+    """``_token_sweep``'s body for one tile, with its D rows ``d_tok``."""
     # --- per-token skip phase (Eq 8-10); b_i via masked-lookup psum
     a = g_vals[word_ids]                                  # (N, g+1)
     ktop = g_idx[word_ids][:, :g]                         # (N, g)
@@ -384,12 +402,13 @@ def _dist_step(word_ids, doc_ids, mask, state, *,
     if layout is None:
         D = state.D[0]
         W = state.W
-        d_tok = D[doc_ids]                                # (N, K_loc)
+        d_rows = lambda d: D[d]                           # noqa: E731
         len_rows = jnp.sum(D, axis=-1, dtype=jnp.float32)   # (M_loc,)
     else:
         d_packed = state.D[0]                             # (M_loc, L)
         W = layout.densify_w(state.W_head, state.W_tail)  # (V, K) exact
-        d_tok = sparse.densify_rows(d_packed[doc_ids], layout.n_topics)
+        d_rows = lambda d: sparse.densify_rows(           # noqa: E731
+            d_packed[d], layout.n_topics)
         # per-doc length from the packed val fields: O(M_loc·L), exact ints
         len_rows = jnp.sum(sparse.unpack_pairs(d_packed)[1],
                            axis=-1).astype(jnp.float32)
@@ -413,9 +432,9 @@ def _dist_step(word_ids, doc_ids, mask, state, *,
     # --- per-token skip phase + combined-sweep phase 2
     len_tot = jax.lax.psum(len_rows, model_axis)
     new_topics, skip, in_m, k1 = _token_sweep(
-        u, word_ids, doc_ids, d_tok, len_tot, W_hat, g_vals, g_idx,
+        u, word_ids, doc_ids, d_rows, len_tot, W_hat, g_vals, g_idx,
         q_prime_w, alpha=alpha, g=g, kb0=kb0, k_local=k_local, my=my,
-        model_axis=model_axis)
+        model_axis=model_axis, tile=cfg.tile_size)
 
     # --- update: incremental ±1 deltas at changed tokens only (the fused
     # step's delta update, per shard). Each token subtracts its old topic and
@@ -641,14 +660,16 @@ class _StreamedDistMixin:
             my = jax.lax.axis_index("model")
             kb0 = my * k_local
             if lay is None:
-                d_tok = d_main[0][doc_r]
+                d_rows = lambda d: d_main[0][d]           # noqa: E731
             else:
-                d_tok = sparse.densify_rows(d_main[0][doc_r], lay.n_topics)
+                d_rows = lambda d: sparse.densify_rows(   # noqa: E731
+                    d_main[0][d], lay.n_topics)
 
             new_topics, skip, in_m, k1 = _token_sweep(
-                u, word_r, doc_r, d_tok, len_tot[0], W_hat, g_vals,
+                u, word_r, doc_r, d_rows, len_tot[0], W_hat, g_vals,
                 g_idx, q_prime, alpha=cfg.alpha_, g=g, kb0=kb0,
-                k_local=k_local, my=my, model_axis="model")
+                k_local=k_local, my=my, model_axis="model",
+                tile=cfg.tile_size)
 
             wgt = mask_r.astype(jnp.int32)
 
@@ -1157,7 +1178,10 @@ class DistLDATrainer(_StreamedDistMixin):
             lay = self.layout
             W = np.asarray(lay.densify_w(state.W_head, state.W_tail))
             s_n, m_loc = self.sc.n_shards, self.sc.m_local
-            flat = jnp.asarray(state.D).reshape(s_n * m_loc, -1)
+            # host copy first: the sharded D must not meet densify's
+            # replicated operands in eager code (an Explicit-axes mesh
+            # rejects mixed shardings)
+            flat = jnp.asarray(np.asarray(state.D).reshape(s_n * m_loc, -1))
             D_sh = np.asarray(sparse.densify_rows(flat, lay.n_topics)) \
                 .reshape(s_n, m_loc, lay.n_topics)
         K = W.shape[1]
@@ -1421,13 +1445,14 @@ class PSDistTrainer:
                 page, cfg=cfg, model_axis="model", n_words=V, g=g,
                 kb0=kb0, k_local=K, colsum=colsum)
             if lay is None:
-                d_tok = d_block[doc_r]
+                d_rows = lambda d: d_block[d]             # noqa: E731
             else:
-                d_tok = sparse.densify_rows(d_block[doc_r], K)
+                d_rows = lambda d: sparse.densify_rows(   # noqa: E731
+                    d_block[d], K)
             new_topics, skip, in_m, k1 = _token_sweep(
-                u_r, word_rel, doc_r, d_tok, len_tot, W_hat, g_vals,
+                u_r, word_rel, doc_r, d_rows, len_tot, W_hat, g_vals,
                 g_idx, q_prime, alpha=cfg.alpha_, g=g, kb0=kb0,
-                k_local=K, my=my, model_axis="model")
+                k_local=K, my=my, model_axis="model", tile=cfg.tile_size)
             wgt = mask_r.astype(jnp.int32)
 
             def _blk(t):

@@ -174,6 +174,11 @@ class LDATrainer:
         self.n_words = corpus.n_words
         self.n_real_tokens = corpus.n_tokens
         self.n_padded_tokens = int(padded.word_ids.shape[0])
+        if config.sampler == "warp" and config.impl == "pallas":
+            # the fused warp chunk falls back to a full-vocabulary window,
+            # so the whole vocabulary must fit the kernel's VMEM bound
+            from repro.kernels.sample_warp import check_window
+            check_window(self.n_words, config.n_topics)
         self._sampler = self._make_sampler()
 
     # -- state ------------------------------------------------------------

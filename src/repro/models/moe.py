@@ -22,10 +22,10 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map as _shard_map
 
 from repro.models import layers
 from repro.models.config import ModelConfig
-from repro.runtime.compat import shard_map as _shard_map
 from repro.runtime.sharding import constrain
 
 __all__ = ["init_moe", "moe_ffn", "router_load_stats"]

@@ -1,44 +1,22 @@
-"""Version compatibility shims for the jax API surface this repo uses.
+"""Mesh construction with the axis types this repo's sharding code expects.
 
-The repo targets the modern names (``jax.shard_map``, ``jax.make_mesh`` with
-``axis_types``, ``pltpu.CompilerParams``); older jax releases (e.g. 0.4.x)
-spell them differently. Everything funnels through here so call sites stay
-on the modern spelling.
+``jax.make_mesh`` defaults to ``Explicit`` axes, under which eager code
+that mixes a sharded and a replicated operand raises. The trainers and
+tests build their meshes here, with ``Auto`` axes unless asked otherwise.
 """
 
 from __future__ import annotations
 
 import jax
 
-__all__ = ["shard_map", "make_mesh", "tpu_compiler_params"]
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-    """jax.shard_map, falling back to jax.experimental.shard_map.
-
-    The old API calls the replication check ``check_rep``; semantics match.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
+__all__ = ["make_mesh"]
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None,
               explicit: bool = False):
-    """jax.make_mesh; ``axis_types`` only where the installed jax has it."""
+    """``jax.make_mesh`` with every axis ``Auto`` (or ``Explicit``)."""
+    kind = jax.sharding.AxisType.Explicit if explicit \
+        else jax.sharding.AxisType.Auto
     kw = {} if devices is None else {"devices": devices}
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        kind = axis_type.Explicit if explicit else axis_type.Auto
-        kw["axis_types"] = (kind,) * len(axis_names)
-    return jax.make_mesh(axis_shapes, axis_names, **kw)
-
-
-def tpu_compiler_params(**kwargs):
-    """pltpu.CompilerParams (new) / pltpu.TPUCompilerParams (0.4.x)."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(kind,) * len(axis_names), **kw)

@@ -401,19 +401,18 @@ class FusedPipeline:
         return vmin.astype(jnp.int32), vmax.astype(jnp.int32)
 
     def _max_chunk_span(self, surv_idx, n_chunks: int, capacity: int, *,
-                        word_ids=None, n_stream: int | None = None):
+                        word_ids, n_stream: int | None = None):
         """Max word span over the scan's survivor tiles (for re-planning).
 
         One (n_chunks·capacity) gather per iteration — O(N) like the
         compaction itself; read back on the host only between scans.
-        Defaults to the resident stream; the streaming pipeline passes its
-        shard-local (word_ids, n_stream).
+        ``n_stream`` defaults to the resident stream; the streaming
+        pipeline passes its shard-local (word_ids, n_stream).
         """
         n = self.n_tokens if n_stream is None else n_stream
-        w_arr = self.word_ids if word_ids is None else word_ids
         idx_m = surv_idx.reshape(n_chunks, capacity)
         valid = idx_m < n
-        v = w_arr[jnp.minimum(idx_m, n - 1)]
+        v = word_ids[jnp.minimum(idx_m, n - 1)]
         vmin = jnp.min(jnp.where(valid, v, self.n_words - 1), axis=1)
         vmax = jnp.max(jnp.where(valid, v, 0), axis=1)
         span = jnp.where(jnp.any(valid, axis=1), vmax - vmin + 1, 0)
@@ -435,30 +434,26 @@ class FusedPipeline:
         alpha = cfg.alpha_
         use_tiles = self._use_tiles(win_words)
 
-        def sample_chunk(idx):
-            u_c, v_c, d_c = u[idx], word_ids[idx], doc_ids[idx]
-            if cfg.impl == "pallas":
+        if cfg.impl == "pallas":
+
+            def draw(idx, first):
+                u_c, v_c, d_c = u[idx], word_ids[idx], doc_ids[idx]
                 d_rows = D[d_c]
-                if not use_tiles:
+                if first is None:
                     t_c, m, s, q = _fused.sample_fused(
                         u_c, d_rows, W_hat[v_c], alpha=alpha,
                         interpret=self._interpret)
                 else:
-                    first, last = self._chunk_run(v_c, idx, n_stream)
-
-                    def tiled(_):
-                        return _fused.sample_fused_tiled(
-                            u_c, d_rows, W_hat, v_c, first, alpha=alpha,
-                            win_words=win_words, interpret=self._interpret)
-
-                    def untiled(_):
-                        return _fused.sample_fused(
-                            u_c, d_rows, W_hat[v_c], alpha=alpha,
-                            interpret=self._interpret)
-
-                    t_c, m, s, q = jax.lax.cond(
-                        last - first < win_words, tiled, untiled, None)
+                    t_c, m, s, q = _fused.sample_fused_tiled(
+                        u_c, d_rows, W_hat, v_c, first, alpha=alpha,
+                        win_words=win_words, interpret=self._interpret)
                 return t_c, u_c * (m + s + q) < m
+
+            return self._tile_dispatch(draw, word_ids, win_words=win_words,
+                                       n_stream=n_stream)
+
+        def sample_chunk(idx):
+            u_c, v_c, d_c = u[idx], word_ids[idx], doc_ids[idx]
             if not use_tiles:
                 return three_branch.exact_three_branch(
                     u_c, v_c, d_c, k1_per_word, D, W_hat,
@@ -485,6 +480,62 @@ class FusedPipeline:
                                 None)
 
         return sample_chunk
+
+    def _tile_dispatch(self, draw, word_ids, *, win_words: int,
+                       n_stream: int | None):
+        """``sample_chunk(idx)`` running a Pallas ``draw(idx, first)`` one
+        token tile at a time (``three_branch.map_token_tiles``). With tiles
+        on, a chunk whose live word run fits the window draws against it
+        (``first`` is the run's first word); any other chunk passes
+        ``first=None``, the per-token row gather."""
+        tile = self.config.tile_size
+
+        def untiled(idx):
+            return three_branch.map_token_tiles(lambda t: draw(t, None),
+                                                idx, tile)
+
+        if not self._use_tiles(win_words):
+            return untiled
+
+        def sample_chunk(idx):
+            first, last = self._chunk_run(word_ids[idx], idx, n_stream)
+            return jax.lax.cond(
+                last - first < win_words,
+                lambda i: three_branch.map_token_tiles(
+                    lambda t: draw(t, first), i, tile),
+                untiled, idx)
+
+        return sample_chunk
+
+    def _sparse_tail_sampler(self, u, word_ids, doc_ids, d_packed, d_dense,
+                             W_hat, stats_w, *, win_words: int,
+                             n_stream: int | None = None):
+        """Phase-2 ``sample_chunk(idx)`` for tail-word survivors under
+        ``tail_sampler="sparse"`` (the hybrid pipelines): the O(L) Pallas
+        draw over packed D rows plus the Q' fallback
+        (kernels/ops.sparse_tail_draw, or the window-resolved
+        ``sparse_tail_draw_tiled`` under ``balance="tiles"``)."""
+        alpha = self.config.alpha_
+        k1_per_word = stats_w.k[:, 0]
+
+        def draw(idx, first):
+            u_c, v_c, d_c = u[idx], word_ids[idx], doc_ids[idx]
+            k1 = k1_per_word[v_c]
+            b1 = d_dense[d_c, k1].astype(jnp.float32)
+            if first is None:
+                t_c, _nq, in_m = kops.sparse_tail_draw(
+                    u_c, d_packed[d_c], W_hat[v_c], k1, stats_w.a[v_c, 0],
+                    b1, stats_w.q_prime[v_c], alpha=alpha,
+                    interpret=self._interpret)
+            else:
+                t_c, _nq, in_m = kops.sparse_tail_draw_tiled(
+                    u_c, d_packed[d_c], W_hat, v_c, first, k1_per_word,
+                    stats_w.a[:, 0], stats_w.q_prime, b1, alpha=alpha,
+                    win_words=win_words, interpret=self._interpret)
+            return t_c, in_m
+
+        return self._tile_dispatch(draw, word_ids, win_words=win_words,
+                                   n_stream=n_stream)
 
     def _warp_chunk_sampler(self, topics, t_doc, t_word, u_draw, u_acc,
                             word_ids, doc_ids, D, W_hat, prop, *,
@@ -547,8 +598,8 @@ class FusedPipeline:
 
         return sample_chunk
 
-    def _warp_iteration(self, fstate: FusedState, prop, *, capacity: int,
-                        win_words: int):
+    def _warp_iteration(self, fstate: FusedState, prop, toks, *,
+                        capacity: int, win_words: int):
         """One warp MH iteration: proposals → chain → delta update.
 
         Slots into the identical survivor-compaction machinery as the
@@ -562,14 +613,14 @@ class FusedPipeline:
         """
         cfg = self.config
         n, n_cycles = self.n_tokens, cfg.mh_cycles
-        word_ids, doc_ids, mask = self.word_ids, self.doc_ids, self.mask
+        word_ids, doc_ids, mask, doc_index = toks
         topics, D, W, colsum, key, iteration = fstate
         w_til, tables, _squeue, _lqueue, _n_small = prop
 
         key, sub = jax.random.split(key)
         kd, kw, ka = jax.random.split(sub, 3)
         W_hat = esca.compute_w_hat_from_colsum(W, colsum, cfg.beta)
-        t_doc = mh.doc_proposals(kd, topics, doc_ids, self.doc_index,
+        t_doc = mh.doc_proposals(kd, topics, doc_ids, doc_index,
                                  n_topics=cfg.n_topics, alpha=cfg.alpha_,
                                  n_cycles=n_cycles)
         t_word, u_draw = mh.word_proposals(kw, word_ids, tables,
@@ -582,7 +633,8 @@ class FusedPipeline:
         n_chunks = max(1, -(-n // capacity))
         surv_idx = three_branch.compact_survivor_indices(
             rank, skip, n_chunks * capacity)
-        max_span = self._max_chunk_span(surv_idx, n_chunks, capacity) \
+        max_span = self._max_chunk_span(
+            surv_idx, n_chunks, capacity, word_ids=word_ids) \
             if self.balance == "tiles" else jnp.int32(0)
 
         sample_chunk = self._warp_chunk_sampler(
@@ -602,11 +654,11 @@ class FusedPipeline:
 
     # -- the fused iteration body (traced; no host interaction) ------------
 
-    def _iteration(self, fstate: FusedState, *, capacity: int,
+    def _iteration(self, fstate: FusedState, toks, *, capacity: int,
                    win_words: int):
         cfg = self.config
         alpha, g = cfg.alpha_, cfg.g
-        word_ids, doc_ids, mask = self.word_ids, self.doc_ids, self.mask
+        word_ids, doc_ids, mask = toks
         n = self.n_tokens
         topics, D, W, colsum, key, iteration = fstate
 
@@ -621,7 +673,8 @@ class FusedPipeline:
         n_chunks = max(1, -(-n // capacity))
         surv_idx = three_branch.compact_survivor_indices(
             rank, dec.skip, n_chunks * capacity)
-        max_span = self._max_chunk_span(surv_idx, n_chunks, capacity) \
+        max_span = self._max_chunk_span(
+            surv_idx, n_chunks, capacity, word_ids=word_ids) \
             if self.balance == "tiles" else jnp.int32(0)
 
         sample_chunk = self._dense_chunk_sampler(
@@ -643,11 +696,20 @@ class FusedPipeline:
 
     # -- compiled entry points --------------------------------------------
 
+    def _token_args(self) -> tuple:
+        """The corpus arrays the compiled scan reads, handed to it as
+        arguments: jit embeds a closed-over array in the program as a
+        constant, which is a copy in every executable and gigabytes of
+        program text at corpus scale."""
+        toks = (self.word_ids, self.doc_ids, self.mask)
+        return toks + (self.doc_index,) if self.sampler == "warp" else toks
+
     def _get_fn(self, n_iters: int) -> Callable:
-        """(state[, prop]) -> (state, stats, n_surv, max_span) for a scan.
+        """(state, toks[, prop]) -> (state, stats, n_surv, max_span) for
+        a scan; ``toks`` is ``_token_args()``.
 
         With ``sampler="warp"`` the compiled scan takes the scan-start
-        proposal state as a second (undonated) argument — the tables stay
+        proposal state as a third (undonated) argument — the tables stay
         fixed across the scan's iterations (the staleness argument,
         DESIGN.md SS12) while the counts keep moving under donation.
         """
@@ -657,20 +719,21 @@ class FusedPipeline:
             capacity, win = self.capacity, self.win_words
             if self.sampler == "warp":
 
-                def multi(fstate, prop):
+                def multi(fstate, toks, prop):
                     def body(carry, _):
                         st, stats, n_surv, span = self._warp_iteration(
-                            carry, prop, capacity=capacity, win_words=win)
+                            carry, prop, toks, capacity=capacity,
+                            win_words=win)
                         return st, (stats, n_surv, span)
                     fstate, (stats, n_surv, span) = jax.lax.scan(
                         body, fstate, None, length=n_iters)
                     return fstate, stats, n_surv, span
             else:
 
-                def multi(fstate):
+                def multi(fstate, toks):
                     def body(carry, _):
                         st, stats, n_surv, span = self._iteration(
-                            carry, capacity=capacity, win_words=win)
+                            carry, toks, capacity=capacity, win_words=win)
                         return st, (stats, n_surv, span)
                     fstate, (stats, n_surv, span) = jax.lax.scan(
                         body, fstate, None, length=n_iters)
@@ -682,8 +745,9 @@ class FusedPipeline:
 
     def _dispatch(self, fn: Callable, fstate):
         if self.sampler == "warp":
-            return fn(fstate, self._build_proposal(fstate))
-        return fn(fstate)
+            return fn(fstate, self._token_args(),
+                      self._build_proposal(fstate))
+        return fn(fstate, self._token_args())
 
     def step(self, fstate: FusedState):
         """One fused iteration — a single donated dispatch."""
@@ -791,8 +855,6 @@ class HybridFusedPipeline(FusedPipeline):
         from repro.lda.model import HybridLayout
         self.layout = HybridLayout.build(corpus, config)
         head = np.asarray(word_ids) < self.layout.v_dense
-        self.head_mask = jnp.asarray(head)
-        self.tail_mask = jnp.asarray(~head)
         self.n_head = int(head.sum())
         self.n_tail = int((~head).sum())
 
@@ -840,7 +902,8 @@ class HybridFusedPipeline(FusedPipeline):
             overflow = overflow + ov_b
         return d_packed, w_head, tuple(new_tail), overflow
 
-    def _warp_iteration(self, hs, prop, *, capacity: int, win_words: int):
+    def _warp_iteration(self, hs, prop, toks, *, capacity: int,
+                        win_words: int):
         """The warp MH iteration over the hybrid packed state: densify
         once (exact integers), run the dense warp machinery bit-for-bit,
         repack once. The T partition never splits — the MH chain reads
@@ -848,7 +911,7 @@ class HybridFusedPipeline(FusedPipeline):
         route identically (``tail_sampler`` is an exact-sampler knob)."""
         cfg, lay = self.config, self.layout
         n, n_cycles = self.n_tokens, cfg.mh_cycles
-        word_ids, doc_ids, mask = self.word_ids, self.doc_ids, self.mask
+        word_ids, doc_ids, mask, doc_index = toks
         k_total = lay.n_topics
         topics, d_packed, w_head, w_tail, colsum, overflow, key, iteration \
             = hs
@@ -862,7 +925,7 @@ class HybridFusedPipeline(FusedPipeline):
         w_int = jnp.concatenate(w_parts, axis=0) if len(w_parts) > 1 \
             else w_head
         w_hat = esca.compute_w_hat_from_colsum(w_int, colsum, cfg.beta)
-        t_doc = mh.doc_proposals(kd, topics, doc_ids, self.doc_index,
+        t_doc = mh.doc_proposals(kd, topics, doc_ids, doc_index,
                                  n_topics=cfg.n_topics, alpha=cfg.alpha_,
                                  n_cycles=n_cycles)
         t_word, u_draw = mh.word_proposals(kw, word_ids, tables,
@@ -875,7 +938,8 @@ class HybridFusedPipeline(FusedPipeline):
         n_chunks = max(1, -(-n // capacity))
         surv_idx = three_branch.compact_survivor_indices(
             rank, skip, n_chunks * capacity)
-        max_span = self._max_chunk_span(surv_idx, n_chunks, capacity) \
+        max_span = self._max_chunk_span(
+            surv_idx, n_chunks, capacity, word_ids=word_ids) \
             if self.balance == "tiles" else jnp.int32(0)
 
         sample_chunk = self._warp_chunk_sampler(
@@ -900,10 +964,10 @@ class HybridFusedPipeline(FusedPipeline):
 
     # -- the fused iteration body (traced; no host interaction) ------------
 
-    def _iteration(self, hs, *, capacity: int, win_words: int):
+    def _iteration(self, hs, toks, *, capacity: int, win_words: int):
         cfg, lay = self.config, self.layout
         alpha, g = cfg.alpha_, cfg.g
-        word_ids, doc_ids, mask = self.word_ids, self.doc_ids, self.mask
+        word_ids, doc_ids, mask = toks
         n = self.n_tokens
         k_total = lay.n_topics
         v_dense = lay.v_dense
@@ -925,47 +989,21 @@ class HybridFusedPipeline(FusedPipeline):
         dec = three_branch.skip_phase(u, word_ids, doc_ids, d_dense,
                                       stats_w, g=g, alpha=alpha)
         k1_per_word = stats_w.k[:, 0]
-        use_tiles = self._use_tiles(win_words)
 
         dense_chunk = self._dense_chunk_sampler(
             u, word_ids, doc_ids, d_dense, w_hat, k1_per_word,
             win_words=win_words)
-
-        def sparse_tail_chunk(idx):
-            u_c, v_c, d_c = u[idx], word_ids[idx], doc_ids[idx]
-            k1 = k1_per_word[v_c]
-            b1 = d_dense[d_c, k1].astype(jnp.float32)
-            if not use_tiles:
-                t_c, _needs_q, in_m = kops.sparse_tail_draw(
-                    u_c, d_packed[d_c], w_hat[v_c], k1, stats_w.a[v_c, 0],
-                    b1, stats_w.q_prime[v_c], alpha=alpha,
-                    interpret=self._interpret)
-                return t_c, in_m
-            first, last = self._chunk_run(v_c, idx)
-
-            def tiled(_):
-                t_c, _nq, in_m = kops.sparse_tail_draw_tiled(
-                    u_c, d_packed[d_c], w_hat, v_c, first, k1_per_word,
-                    stats_w.a[:, 0], stats_w.q_prime, b1, alpha=alpha,
-                    win_words=win_words, interpret=self._interpret)
-                return t_c, in_m
-
-            def untiled(_):
-                t_c, _nq, in_m = kops.sparse_tail_draw(
-                    u_c, d_packed[d_c], w_hat[v_c], k1, stats_w.a[v_c, 0],
-                    b1, stats_w.q_prime[v_c], alpha=alpha,
-                    interpret=self._interpret)
-                return t_c, in_m
-
-            return jax.lax.cond(last - first < win_words, tiled, untiled,
-                                None)
+        sparse_tail_chunk = self._sparse_tail_sampler(
+            u, word_ids, doc_ids, d_packed, d_dense, w_hat, stats_w,
+            win_words=win_words)
 
         # -- phase 2, dispatched by the T partition (static split). With
         # the exact tail sampler both partitions route identically, so they
         # run as ONE compaction (bit-equal to the dense pipeline's order).
         if cfg.tail_sampler == "sparse" and self.n_tail:
-            segments = [(self.head_mask, self.n_head, dense_chunk),
-                        (self.tail_mask, self.n_tail, sparse_tail_chunk)]
+            head_mask = word_ids < v_dense
+            segments = [(head_mask, self.n_head, dense_chunk),
+                        (~head_mask, self.n_tail, sparse_tail_chunk)]
         else:
             segments = [(None, n, dense_chunk)]
         new_topics = dec.k1                      # skipped ⇒ K1 everywhere
@@ -983,7 +1021,8 @@ class HybridFusedPipeline(FusedPipeline):
             if self.balance == "tiles":
                 max_span = jnp.maximum(
                     max_span,
-                    self._max_chunk_span(surv_idx, n_chunks, capacity))
+                    self._max_chunk_span(surv_idx, n_chunks, capacity,
+                                         word_ids=word_ids))
             new_topics, in_m_seg = three_branch.run_survivor_chunks(
                 surv_idx, n_surv, new_topics,
                 capacity=capacity, n_chunks=n_chunks, sample_chunk=chunk_fn)
@@ -2378,15 +2417,9 @@ class StreamingHybridPipeline(StreamingPipeline):
                     u, word_l, doc_s, d_dense, W_hat, k1_per_word,
                     win_words=V, n_stream=L)
 
-                def sparse_tail_chunk(idx):
-                    u_c, v_c, d_c = u[idx], word_l[idx], doc_s[idx]
-                    k1 = k1_per_word[v_c]
-                    b1 = d_dense[d_c, k1].astype(jnp.float32)
-                    t_c, _nq, in_m = kops.sparse_tail_draw(
-                        u_c, d_packed[d_c], W_hat[v_c], k1,
-                        stats_w.a[v_c, 0], b1, stats_w.q_prime[v_c],
-                        alpha=cfg.alpha_, interpret=self._interpret)
-                    return t_c, in_m
+                sparse_tail_chunk = self._sparse_tail_sampler(
+                    u, word_l, doc_s, d_packed, d_dense, W_hat, stats_w,
+                    win_words=V, n_stream=L)
 
                 if split_tail:
                     head_mask = word_s < lay.v_dense
@@ -2430,40 +2463,12 @@ class StreamingHybridPipeline(StreamingPipeline):
                                           stats_w, g=cfg.g,
                                           alpha=cfg.alpha_)
             k1_per_word = stats_w.k[:, 0]
-            use_tiles = self._use_tiles(win_words)
             dense_chunk = self._dense_chunk_sampler(
                 u, word_s, doc_s, d_dense, W_hat, k1_per_word,
                 win_words=win_words, n_stream=L)
-
-            def sparse_tail_chunk(idx):
-                u_c, v_c, d_c = u[idx], word_s[idx], doc_s[idx]
-                k1 = k1_per_word[v_c]
-                b1 = d_dense[d_c, k1].astype(jnp.float32)
-                if not use_tiles:
-                    t_c, _nq, in_m = kops.sparse_tail_draw(
-                        u_c, d_packed[d_c], W_hat[v_c], k1,
-                        stats_w.a[v_c, 0], b1, stats_w.q_prime[v_c],
-                        alpha=cfg.alpha_, interpret=self._interpret)
-                    return t_c, in_m
-                first, last = self._chunk_run(v_c, idx, L)
-
-                def tiled(_):
-                    t_c, _nq, in_m = kops.sparse_tail_draw_tiled(
-                        u_c, d_packed[d_c], W_hat, v_c, first,
-                        k1_per_word, stats_w.a[:, 0], stats_w.q_prime,
-                        b1, alpha=cfg.alpha_, win_words=win_words,
-                        interpret=self._interpret)
-                    return t_c, in_m
-
-                def untiled(_):
-                    t_c, _nq, in_m = kops.sparse_tail_draw(
-                        u_c, d_packed[d_c], W_hat[v_c], k1,
-                        stats_w.a[v_c, 0], b1, stats_w.q_prime[v_c],
-                        alpha=cfg.alpha_, interpret=self._interpret)
-                    return t_c, in_m
-
-                return jax.lax.cond(last - first < win_words, tiled,
-                                    untiled, None)
+            sparse_tail_chunk = self._sparse_tail_sampler(
+                u, word_s, doc_s, d_packed, d_dense, W_hat, stats_w,
+                win_words=win_words, n_stream=L)
 
             if split_tail:
                 head_mask = word_s < lay.v_dense

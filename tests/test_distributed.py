@@ -237,7 +237,8 @@ def test_moe_parallel_paths_match_local():
                      rng.integers(0, cfg.vocab_size, (B, S)), jnp.int32),
                  "mask": jnp.ones((B, S), jnp.int32)}
         ref = float(jax.jit(api.loss)(params, batch))
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.runtime.compat import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         for policy in ("tp", "ep"):
             rules = LogicalRules(mesh, policy=policy)
             def f(p, b):

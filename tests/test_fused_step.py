@@ -170,3 +170,34 @@ def test_plan_capacity_buckets():
     assert plan_capacity(0, 10 ** 6) == 2048           # floor
     assert plan_capacity(100_000, 10 ** 6) == 16384    # ~ema/8 -> next pow2
     assert plan_capacity(10 ** 9, 4096) == 4096        # clamped to corpus
+
+
+# ---------------------------------------------------------------------------
+# 5. the corpus arrays reach the compiled scan as arguments
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("extra", [{}, {"format": "hybrid",
+                                        "tail_sampler": "sparse"},
+                                   {"sampler": "warp"}])
+def test_fused_scan_captures_no_corpus_constants(small_corpus, extra):
+    """jit embeds a closed-over array in the program as a constant; the
+    token arrays (N-long) must be arguments, or every executable carries
+    a copy of the corpus."""
+    import warnings
+    cfg = LDAConfig(n_topics=16, tile_size=512, fused=True, **extra)
+    tr = LDATrainer(small_corpus, cfg, _from_engine=True)
+    pipe = tr.fused_pipeline()
+    fs = pipe.from_lda_state(tr.init_state())
+    args = (fs, pipe._token_args())
+    if pipe.sampler == "warp":
+        args += (pipe._build_proposal(fs),)
+    n_bytes = 4 * pipe.n_tokens
+    was = jax.config.jax_captured_constants_warn_bytes
+    jax.config.update("jax_captured_constants_warn_bytes", n_bytes)
+    try:
+        with warnings.catch_warnings(record=True) as seen:
+            warnings.simplefilter("always")
+            pipe._get_fn(1).lower(*args)
+    finally:
+        jax.config.update("jax_captured_constants_warn_bytes", was)
+    assert not [w for w in seen if "constants were captured" in str(w.message)]

@@ -218,11 +218,12 @@ def test_dist_hybrid_matches_dist_dense_bitwise():
         from repro.lda.corpus import synthetic_lda_corpus, relabel_by_frequency
         from repro.lda.model import LDAConfig
         from repro.lda.distributed import DistLDATrainer
+        from repro.runtime.compat import make_mesh
 
         corpus = synthetic_lda_corpus(0, n_docs=80, n_words=100, n_topics=8,
                                       mean_doc_len=50)
         corpus, _ = relabel_by_frequency(corpus)
-        mesh = jax.make_mesh((4, 1), ("data", "model"))
+        mesh = make_mesh((4, 1), ("data", "model"))
         trd = DistLDATrainer(corpus, LDAConfig(n_topics=16, tile_size=512),
                              mesh, pad_multiple=256, _from_engine=True)
         trh = DistLDATrainer(corpus, LDAConfig(n_topics=16, tile_size=512,
@@ -245,7 +246,7 @@ def test_dist_hybrid_matches_dist_dense_bitwise():
         # hybrid needs model axis 1
         try:
             DistLDATrainer(corpus, LDAConfig(n_topics=16, format="hybrid"),
-                           jax.make_mesh((2, 2), ("data", "model")), _from_engine=True)
+                           make_mesh((2, 2), ("data", "model")), _from_engine=True)
             raise SystemExit("expected ValueError")
         except ValueError:
             pass

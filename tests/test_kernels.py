@@ -80,6 +80,44 @@ def test_sample_sparse_vs_ref(n, L, K):
     assert np.mean(np.asarray(tk) != np.asarray(tr_)) < 2e-3
 
 
+def test_sparse_tail_draw_on_sorted_rows_with_empty_slots():
+    """The hybrid state packs D rows sorted with (EMPTY_IDX, 0) padding
+    slots. Their S' mass must be the dense one, Σ_{k≠K1} D·Ŵ — an empty
+    slot carries none (never 0·NaN) — and every topic a real one."""
+    from repro.core.sparse import pack_rows_sorted
+    from repro.kernels import ops as kops
+    rng = np.random.default_rng(11)
+    n, K, L = 300, 64, 24
+    d = np.zeros((n, K), np.int32)
+    for i in range(n):
+        cols = rng.choice(K, rng.integers(1, L // 2), replace=False)
+        d[i, cols] = rng.integers(1, 30, cols.size)
+    packed, _ = pack_rows_sorted(jnp.asarray(d), L)
+    w_rows = rng.random((n, K)).astype(np.float32) * 0.01
+    k1 = rng.integers(0, K, n).astype(np.int32)
+    b1 = d[np.arange(n), k1].astype(np.float32)
+    a1 = w_rows[np.arange(n), k1]
+    alpha = 0.1
+    w_prime = np.where(np.arange(K) == k1[:, None], 0.0, w_rows)
+    qp = alpha * w_prime.sum(axis=1)
+    u = jnp.asarray(rng.random(n).astype(np.float32))
+    topics, needs_q, _ = kops.sparse_tail_draw(
+        u, packed, jnp.asarray(w_rows), jnp.asarray(k1), jnp.asarray(a1),
+        jnp.asarray(b1), jnp.asarray(qp, jnp.float32), alpha=alpha,
+        interpret=True)
+    idx = np.minimum(np.asarray(packed) >> 16 & 0xFFFF, K - 1)
+    w_at = jnp.asarray(np.take_along_axis(w_rows, idx, axis=1))
+    _, _, s_prime = sample_sparse(
+        u, packed, w_at, jnp.asarray(k1), jnp.asarray(a1), jnp.asarray(b1),
+        jnp.asarray(qp, jnp.float32), alpha=alpha, interpret=True)
+    np.testing.assert_allclose(s_prime, (d * w_prime).sum(axis=1),
+                               rtol=1e-5)
+    topics = np.asarray(topics)
+    assert ((topics >= 0) & (topics < K)).all()
+    # Q' is a small share of the mass here; NaN masses send every token there
+    assert np.mean(np.asarray(needs_q)) < 0.5
+
+
 @pytest.mark.parametrize("n,R,K,rpt", [
     (2000, 50, 64, 32),      # narrow rows: pure MXU path
     (5000, 300, 130, 64),    # mixed

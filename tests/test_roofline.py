@@ -59,7 +59,8 @@ def test_analytic_model_calibration():
         from repro.train.train_step import (make_train_step,
             train_state_specs, batch_shardings)
         from repro.train.optimizer import init_opt_state
-        mesh = jax.make_mesh((4, 4), ("data", "model"))
+        from repro.runtime.compat import make_mesh
+        mesh = make_mesh((4, 4), ("data", "model"))
         for arch in ("qwen1.5-0.5b", "mamba2-370m"):
             cfg = dataclasses.replace(REGISTRY[arch], scan_unroll=True,
                                       n_layers=4)

@@ -414,13 +414,14 @@ def test_streamed_distributed_forged_devices():
     from repro.lda.corpus import synthetic_lda_corpus, relabel_by_frequency
     from repro.lda.model import LDAConfig
     from repro.lda.distributed import DistLDATrainer
+    from repro.runtime.compat import make_mesh
     corpus = synthetic_lda_corpus(0, n_docs=80, n_words=100, n_topics=8,
                                   mean_doc_len=50)
     corpus, _ = relabel_by_frequency(corpus)
     for shape, fmt, bal in (((4, 2), "dense", "none"),
                             ((4, 1), "dense", "tiles"),
                             ((8, 1), "hybrid", "none")):
-        mesh = jax.make_mesh(shape, ("data", "model"))
+        mesh = make_mesh(shape, ("data", "model"))
         kw = dict(n_topics=16, tile_size=512, format=fmt, balance=bal)
         tr_r = DistLDATrainer(corpus, LDAConfig(**kw), mesh,
                               pad_multiple=256, _from_engine=True)
