@@ -69,10 +69,6 @@ KERNEL_SLOTS = 416
 # bound.
 LLPT_TOL = 0.01
 
-# lowering and XLA compilation (tracing nests, so it is left out)
-_COMPILE_EVENTS = ("/jax/core/compile/jaxpr_to_mlir_module_duration",
-                   "/jax/core/compile/backend_compile_duration")
-
 
 def say(*parts) -> None:
     print(*parts, flush=True)
@@ -85,23 +81,6 @@ class Fail(RuntimeError):
 def check(ok: bool, what: str) -> None:
     if not ok:
         raise Fail(what)
-
-
-class CompileClock:
-    """Sums JAX's own lowering and backend-compile durations."""
-
-    def __init__(self):
-        import jax
-        self.total = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-
-    def _on_event(self, event, duration, **_):
-        if event in _COMPILE_EVENTS:
-            self.total += duration
-
-    def lap(self) -> float:
-        t, self.total = self.total, 0.0
-        return t
 
 
 # -- 1. device ---------------------------------------------------------------
@@ -272,21 +251,22 @@ def kernel_evidence(engine) -> tuple[bool, int]:
     return pipe._interpret, text.count("tpu_custom_call")
 
 
-def train_phase(name: str, corpus, config, clock: CompileClock, *,
+def train_phase(name: str, corpus, config, *,
                 pallas: bool, **engine_kw):
     import jax
     from repro.lda.api import LDAEngine
+    from repro.runtime import compiles
     t0 = time.perf_counter()
-    clock.lap()
+    c0 = compiles.seconds()         # lowering and XLA compilation
     engine = LDAEngine(corpus, config, **engine_kw)
     engine.fit(0)                                   # build the initial state
     llpt0 = engine.score()
     t_init = time.perf_counter() - t0
-    c_init = clock.lap()
+    c1 = compiles.seconds()
     t1 = time.perf_counter()
     hist = engine.fit(N_ITERS)
     t_fit = time.perf_counter() - t1
-    c_fit = clock.lap()
+    c_fit = compiles.seconds() - c1
     t2 = time.perf_counter()
     llpt = engine.score()
     t_eval = time.perf_counter() - t2
@@ -294,7 +274,7 @@ def train_phase(name: str, corpus, config, clock: CompileClock, *,
     steady = (t_fit - c_fit - t_eval) / N_ITERS
     d_sum, w_sum = count_sums(engine)
     line = {"config": name, "backend": engine.backend_name,
-            "compile_s": round(c_init + c_fit, 1),
+            "compile_s": round(c1 - c0 + c_fit, 1),
             "steady_s_per_iter": round(steady, 3),
             "init_s": round(t_init, 1), "fit_s": round(t_fit, 1),
             "conserved": d_sum == w_sum == corpus.n_tokens,
@@ -344,7 +324,7 @@ def serve_phase(engine, word_map, heldout) -> None:
 
 # -- the runs ----------------------------------------------------------------
 
-def one_chip(clock: CompileClock) -> dict:
+def one_chip() -> dict:
     import jax
     from repro.lda.model import LDAConfig
     device = device_phase(1)
@@ -362,7 +342,7 @@ def one_chip(clock: CompileClock) -> dict:
          True),
     )
     for name, cfg, pallas in configs:
-        engine, _ = train_phase(name, corpus, cfg, clock, pallas=pallas,
+        engine, _ = train_phase(name, corpus, cfg, pallas=pallas,
                                 backend="single")
         if name == "default":
             serve_phase(engine, word_map, heldout)
@@ -372,7 +352,7 @@ def one_chip(clock: CompileClock) -> dict:
     return device
 
 
-def four_chips(clock: CompileClock) -> dict:
+def four_chips() -> dict:
     import jax
     from repro.lda.model import LDAConfig
     from repro.runtime.compat import make_mesh
@@ -384,7 +364,7 @@ def four_chips(clock: CompileClock) -> dict:
     fits_phase(need, devs[:1])
     cfg = LDAConfig(n_topics=N_TOPICS)
     mesh = make_mesh((4, 1), ("data", "model"), devices=devs)
-    engine, llpt4 = train_phase("distributed_4x1", corpus, cfg, clock,
+    engine, llpt4 = train_phase("distributed_4x1", corpus, cfg,
                                 pallas=False, backend="distributed",
                                 mesh=mesh)
     holders = {s.device for s in engine.state.D.addressable_shards
@@ -395,7 +375,7 @@ def four_chips(clock: CompileClock) -> dict:
     check(holders == set(devs) and min(in_use) > 0,
           "a chip holds no shard of the distributed state")
     release(engine)
-    _, llpt1 = train_phase("single_chip", corpus, cfg, clock, pallas=False,
+    _, llpt1 = train_phase("single_chip", corpus, cfg, pallas=False,
                            backend="single")
     diff = abs(llpt4 - llpt1)
     say(f"[four] llpt 4 chips {llpt4:.6f} vs 1 chip {llpt1:.6f}: "
@@ -414,10 +394,9 @@ def main(argv=None) -> int:
     cache = enable_compile_cache()
     warm = os.path.isdir(cache) and bool(os.listdir(cache))
     say(f"[cache] {cache} ({'warm' if warm else 'cold'} at start)")
-    clock = CompileClock()
     t0 = time.perf_counter()
     try:
-        device = four_chips(clock) if args.four_chips else one_chip(clock)
+        device = four_chips() if args.four_chips else one_chip()
     except Fail as e:
         print(f"FAILED: {e}", file=sys.stderr, flush=True)
         return 1

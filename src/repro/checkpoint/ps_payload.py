@@ -52,7 +52,8 @@ class PSPayloadExt:
     done_topics: np.ndarray        # (sum cursors·L,) int32
     owner_starts: np.ndarray       # (n_owners+1,) int64
     owner_rows: list               # per-owner (R_o, K) int32
-    stat_sums: np.ndarray | None   # (S, 4) float64
+    stat_sums: np.ndarray | None   # (S, 5) float64; (S, 4) before the
+    #                                phase-2 slot sum was kept
     n_surv: np.ndarray | None      # (S,) float64
 
     def gather_w(self) -> np.ndarray:
@@ -75,7 +76,7 @@ def pack_ps_payload(*, server, cursors, done_topics, epochs) -> dict:
     workers between rounds), supplying the reporting-only stat sums.
     """
     S = len(cursors)
-    stat_sums = np.zeros((S, 4), np.float64)
+    stat_sums = np.zeros((S, 5), np.float64)
     n_surv = np.zeros(S, np.float64)
     for w, ep in enumerate(epochs):
         if ep is not None:

@@ -52,7 +52,7 @@ from repro.core.sparse import pack_pairs
 __all__ = [
     "WordStats", "word_stats", "SkipDecision", "skip_phase",
     "exact_three_branch", "exact_three_branch_tiled", "ThreeBranchStats",
-    "sample",
+    "chunk_slots", "sample",
     "build_plan", "Plan", "survivor_rank", "compact_survivor_indices",
     "map_token_tiles", "run_survivor_chunks",
 ]
@@ -215,6 +215,20 @@ class ThreeBranchStats(NamedTuple):
     # Q'-branch landings (paper Eq 6's α∘Ŵ' term). Defaults to 0.0 on paths
     # that use the combined S'+Q' sweep and cannot attribute the branch.
     frac_q_branch: jax.Array | float = 0.0
+    # exact-draw slots phase 2 computed, over the tokens (capped at 1):
+    # 1.0 where every token is drawn, ceil(survivors/capacity)·capacity/N
+    # where survivors run in fixed-capacity chunks (``chunk_slots``).
+    # Every sampler in the package computes it; the NaN default only
+    # marks a stats tuple built elsewhere as "not counted".
+    frac_phase2_slots: jax.Array | float = float("nan")
+
+
+def chunk_slots(n_surv, capacity: int, n_slots: int):
+    """Exact-draw slots that ``run_survivor_chunks`` computes for
+    ``n_surv`` survivors: whole chunks of ``capacity``, at most
+    ``n_slots`` (int32, on the device)."""
+    n_run = (jnp.asarray(n_surv, jnp.int32) + capacity - 1) // capacity
+    return jnp.minimum(n_run * capacity, n_slots).astype(jnp.int32)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -238,7 +252,12 @@ def _sample_reference(key, word_ids, doc_ids, old_topics, D, W_hat,
     """Reference path: phase 1 for stats + exact phase 2 for *all* tokens.
 
     Identical output distribution to the compacted path (same u per token);
-    used as the oracle and for small problems.
+    used as the oracle and for small problems. One program
+    (``jit__sample_reference``): its phases carry the nested programs'
+    names in the op metadata (``jit(word_stats)``, ``jit(skip_phase)``,
+    ``jit(exact_three_branch)``). As programs of their own the skip test
+    and phase 2 took 58% longer on a TPU v5e than together, so they stay
+    together.
     """
     stats_w = word_stats(W_hat, g=g, alpha=alpha)
     n = word_ids.shape[0]
@@ -254,6 +273,7 @@ def _sample_reference(key, word_ids, doc_ids, old_topics, D, W_hat,
         frac_m_final=jnp.mean(in_m.astype(jnp.float32)),
         frac_unchanged=jnp.mean((new_topics == old_topics).astype(jnp.float32)),
         frac_at_max=jnp.mean((new_topics == dec.k1).astype(jnp.float32)),
+        frac_phase2_slots=None,     # no output: ``sample`` sets it
     )
     return new_topics, st
 
@@ -367,6 +387,8 @@ def _sample_compacted(key, word_ids, doc_ids, old_topics, D, W_hat,
         frac_m_final=jnp.mean((dec.skip | in_m_acc).astype(jnp.float32)),
         frac_unchanged=jnp.mean((new_topics == old_topics).astype(jnp.float32)),
         frac_at_max=jnp.mean((new_topics == dec.k1).astype(jnp.float32)),
+        frac_phase2_slots=chunk_slots(n_surv, capacity, n).astype(
+            jnp.float32) / max(n, 1),
     )
     return new_topics, st
 
@@ -383,9 +405,13 @@ def sample(key, plan: Plan, word_ids, doc_ids, old_topics, D, W, config):
     alpha, beta = config.alpha_, config.beta
     W_hat = esca.compute_w_hat(W, beta)
     if plan.capacity is None:
-        return _sample_reference(key, word_ids, doc_ids, old_topics, D, W_hat,
-                                 g=plan.g, alpha=alpha,
-                                 tile_size=plan.tile_size)
+        new_topics, st = _sample_reference(
+            key, word_ids, doc_ids, old_topics, D, W_hat, g=plan.g,
+            alpha=alpha, tile_size=plan.tile_size)
+        # every token is drawn. Set outside the program so its outputs,
+        # and so its compiled form, stay as they were: on a TPU v5e small
+        # changes to this program's outputs moved its time by up to 60%
+        return new_topics, st._replace(frac_phase2_slots=np.float32(1.0))
     return _sample_compacted(key, word_ids, doc_ids, old_topics, D, W_hat,
                              g=plan.g, alpha=alpha, capacity=plan.capacity,
                              tile_size=plan.tile_size)

@@ -96,6 +96,7 @@ def histogram_partials(row_ids: jax.Array, topics: jax.Array,
                                   n_kblocks * block_k), jnp.int32),
             jax.ShapeDtypeStruct((1, n), jnp.int32),
         ),
+        name="histogram_partials",
         interpret=interpret,
     )(tile_bases, row_ids[None], topics[:, None], weights[None])
     return partials[:, :, :n_topics], covered[0] != 0

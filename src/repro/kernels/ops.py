@@ -66,6 +66,7 @@ def sample_tokens(key, word_ids, doc_ids, old_topics, D, W_hat, *,
         frac_unchanged=jnp.mean((topics == old_topics).astype(jnp.float32)),
         frac_at_max=jnp.mean((topics == k1).astype(jnp.float32)),
         frac_q_branch=jnp.mean(in_q.astype(jnp.float32)),
+        frac_phase2_slots=jnp.float32(1.0),   # the kernel draws every token
     )
     return topics, stats
 
@@ -184,6 +185,7 @@ def sample_tokens_sparse_d(key, word_ids, doc_ids, old_topics,
         frac_unchanged=jnp.mean((topics == old_topics).astype(jnp.float32)),
         frac_at_max=jnp.mean((topics == k1).astype(jnp.float32)),
         frac_q_branch=jnp.mean(needs_q.astype(jnp.float32)),
+        frac_phase2_slots=jnp.float32(1.0),   # the kernel draws every token
     )
     return topics, stats
 

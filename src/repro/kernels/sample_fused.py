@@ -281,6 +281,7 @@ def sample_fused(u: jax.Array, d_rows: jax.Array, w_rows: jax.Array, *,
         scratch_shapes=_scratch(tile_t),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+        name="sample_fused",
         interpret=interpret,
     )(u[:, None], d_rows, w_rows)
     return _columns(outs, n)
@@ -354,6 +355,7 @@ def sample_fused_tiled(u: jax.Array, d_rows: jax.Array, w_hat: jax.Array,
         scratch_shapes=_scratch(tile_t),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+        name="sample_fused_tiled",
         interpret=interpret,
     )(u[:, None], local[:, None], d_rows, window)
     return _columns(outs, n)

@@ -153,6 +153,7 @@ def sample_sparse(u: jax.Array, packed_rows: jax.Array, w_at_idx: jax.Array,
         in_specs=[tok, mat, mat, tok, tok, tok, tok],
         out_specs=(tok, tok, tok),
         out_shape=_out_shapes(n_tiles * tile_t),
+        name="sample_sparse",
         interpret=interpret,
     )(col(u), packed_rows, w_at_idx, col(k1), col(a1, 1.0), col(b1),
       col(q_prime))
@@ -206,6 +207,7 @@ def sample_sparse_tiled(u: jax.Array, packed_rows: jax.Array,
         in_specs=[tok, mat, mat, tok, tok, win_spec, win_spec, win_spec],
         out_specs=(tok, tok, tok),
         out_shape=_out_shapes(n_tiles * tile_t),
+        name="sample_sparse_tiled",
         interpret=interpret,
     )(col(u), packed_rows, w_at_idx, col(local), col(b1), k1_win, a1_win,
       qp_win)

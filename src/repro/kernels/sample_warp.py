@@ -216,6 +216,7 @@ def sample_warp_tiled(s0, d_rows, t_doc, u_draw, u_acc, w_hat, w_til,
                    jax.ShapeDtypeStruct((n_tiles * tile_t, 1), jnp.int32)),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
+        name="sample_warp_tiled",
         interpret=interpret,
     )(s0[:, None], local[:, None], tdoc_t, udraw_t, uacc_t, d_rows, w_win,
       t_win, sq_win, lq_win, ns_win)

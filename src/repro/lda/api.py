@@ -69,6 +69,7 @@ from repro.core import llpt as llpt_mod, three_branch
 from repro.lda.corpus import Corpus, from_documents, relabel_by_frequency
 from repro.lda.model import LDAConfig
 from repro.lda.trainer import run_boundary_chunked
+from repro.runtime import compiles
 from repro.runtime.fault import (RestartReport, StepTimer, SupervisePolicy,
                                  is_oom_error, supervised_loop)
 
@@ -687,7 +688,8 @@ class _DistBackend:
             carry["s"], stats = tr.run_fused(carry["s"], chunk)
             jax.block_until_ready(carry["s"].topics)
             if self.config.selfcheck:
-                tr.selfcheck(carry["s"])
+                with jax.profiler.TraceAnnotation("lda.selfcheck"):
+                    tr.selfcheck(carry["s"])
             return stats
 
         try:
@@ -910,11 +912,19 @@ class LDAEngine:
         an OOM on the resident path degrades once to streamed residency,
         and the returned history carries a ``"restart_report"`` entry
         (also ``engine.restart_report``). Requires a checkpoint manager.
+
+        ``history["lowered"]`` lists the programs JAX lowered during the
+        call, in order (``repro.runtime.compiles``); a program lowered
+        after the call's first iteration is also named through ``log_fn``.
         """
+        lowered = compiles.mark()
         if supervise is not None and supervise is not False:
             policy = SupervisePolicy() if supervise is True else supervise
-            return self._fit_supervised(n_iters, policy, log_fn=log_fn,
+            hist = self._fit_supervised(n_iters, policy, log_fn=log_fn,
                                         checkpoint_every=checkpoint_every)
+            hist["lowered"] = compiles.since(lowered)
+            self.history.setdefault("lowered", []).extend(hist["lowered"])
+            return hist
         if self._state is None:
             self._state = self._backend.restore_or_init()
         self._state, hist = self._backend.run(
@@ -922,6 +932,7 @@ class LDAEngine:
             on_chunk=(self._publish_live if self._subscribers else None))
         if self._subscribers:
             self.publish_serving()      # final state after the run
+        hist["lowered"] = compiles.since(lowered)
         for k, v in hist.items():
             self.history.setdefault(k, []).extend(v)
         return hist
@@ -1092,7 +1103,7 @@ class LDAEngine:
                 mgr.save(it * (R + 1), tr.host_payload(ss))
                 if self._subscribers:   # aligned clock == exact counts
                     self._notify(self._backend.dense_W(ss), 0, 1, it)
-                _ns, sums = ss.stat_rounds.pop(it0, (0, np.zeros(4)))
+                _ns, sums = ss.stat_rounds.pop(it0, (0, np.zeros(5)))
                 if it % self.config.eval_every == 0 or first:
                     first = False
                     m = np.asarray(sums, np.float64) / denom
@@ -1105,7 +1116,8 @@ class LDAEngine:
                                     "frac_m_final": float(m[1]),
                                     "frac_unchanged": float(m[2]),
                                     "frac_at_max": float(m[3]),
-                                    "frac_q_branch": 0.0}]})
+                                    "frac_q_branch": 0.0,
+                                    "frac_phase2_slots": float(m[4])}]})
                     if log_fn:
                         log_fn(f"iter={it:4d} llpt={merged['llpt'][-1]:+.4f}"
                                f" tok/s={n_tok / dt:,.0f}")

@@ -210,7 +210,7 @@ class _DistEpochCarry:
     stats_parts: list = dataclasses.field(default_factory=list)
     n_surv: float = 0.0
     stat_sums: np.ndarray = dataclasses.field(
-        default_factory=lambda: np.zeros(4, np.float64))
+        default_factory=lambda: np.zeros(5, np.float64))
 
 
 @dataclasses.dataclass
@@ -451,13 +451,16 @@ def _dist_step(word_ids, doc_ids, mask, state, *,
 
     old_rel, w_old = _blk(topics)
     t_rel, w_new = _blk(new_topics)
-    dW_local = jnp.zeros((n_words, k_local), jnp.int32
-                         ).at[word_ids, old_rel].add(-w_old
-                         ).at[word_ids, t_rel].add(w_new)
-    dW = jax.lax.psum(dW_local, data_axes)                # delta all-reduce
+    with jax.named_scope("lda.count_update"):
+        dW_local = jnp.zeros((n_words, k_local), jnp.int32
+                             ).at[word_ids, old_rel].add(-w_old
+                             ).at[word_ids, t_rel].add(w_new)
+    with jax.named_scope("lda.w_psum"):
+        dW = jax.lax.psum(dW_local, data_axes)            # delta all-reduce
     if layout is None:
-        D_new = D.at[doc_ids, old_rel].add(-w_old) \
-                 .at[doc_ids, t_rel].add(w_new)
+        with jax.named_scope("lda.count_update"):
+            D_new = D.at[doc_ids, old_rel].add(-w_old) \
+                     .at[doc_ids, t_rel].add(w_new)
         if shared is not None:
             # Dissected docs (balance="tiles"): every holder applied its
             # LOCAL deltas above; add the other shards' deltas so each
@@ -476,8 +479,9 @@ def _dist_step(word_ids, doc_ids, mask, state, *,
         # tokens only — unchanged tokens are a no-op in both layouts). The
         # drop count psums into the replicated overflow tripwire.
         chg = wgt * (topics != new_topics).astype(jnp.int32)
-        D_new, drop = sparse.ell_apply_deltas(
-            d_packed, doc_ids, topics, new_topics, chg)
+        with jax.named_scope("lda.count_update"):
+            D_new, drop = sparse.ell_apply_deltas(
+                d_packed, doc_ids, topics, new_topics, chg)
         overflow = state.overflow + jax.lax.psum(drop, data_axes)
         # Replicated HybridW: the identical psum'd delta lands on every
         # data shard; the tail repacks from the updated dense rows (exact —
@@ -495,6 +499,8 @@ def _dist_step(word_ids, doc_ids, mask, state, *,
         frac_unchanged=_avg((new_topics == topics).astype(jnp.float32)),
         frac_at_max=_avg((new_topics == k1).astype(jnp.float32)),
         frac_q_branch=jnp.float32(0.0),   # combined sweep: not attributed
+        # the combined sweep draws every token: no survivor compaction
+        frac_phase2_slots=_avg(jnp.ones_like(fmask)),
     )
     if layout is None:
         new_state = DistLDAState(
@@ -681,10 +687,11 @@ class _StreamedDistMixin:
 
             old_rel, w_old = _blk(topics)
             t_rel, w_new = _blk(new_topics)
-            dD = deltas[0][0].at[doc_r, old_rel].add(-w_old) \
-                             .at[doc_r, t_rel].add(w_new)
-            dW = deltas[1][0].at[word_r, old_rel].add(-w_old) \
-                             .at[word_r, t_rel].add(w_new)
+            with jax.named_scope("lda.count_update"):
+                dD = deltas[0][0].at[doc_r, old_rel].add(-w_old) \
+                                 .at[doc_r, t_rel].add(w_new)
+                dW = deltas[1][0].at[word_r, old_rel].add(-w_old) \
+                                 .at[word_r, t_rel].add(w_new)
             out_deltas = [dD[None], dW[None]]
             if has_shared:
                 n_sh = deltas[2].shape[1]
@@ -700,7 +707,8 @@ class _StreamedDistMixin:
                 _tot(skip.astype(jnp.float32)),
                 _tot((skip | in_m).astype(jnp.float32)),
                 _tot((new_topics == topics).astype(jnp.float32)),
-                _tot((new_topics == k1).astype(jnp.float32))])
+                _tot((new_topics == k1).astype(jnp.float32)),
+                _tot(jnp.ones_like(fmask))])      # every token is drawn
             n_surv = _tot((~skip).astype(jnp.float32))
             return new_topics[None], tuple(out_deltas), n_surv, sums
 
@@ -724,7 +732,8 @@ class _StreamedDistMixin:
         tok, counts_s, derived_s, deltas_s = self._stream_specs()
 
         def end(counts, deltas, *shared_rows):
-            dW_tot = jax.lax.psum(deltas[1][0], daxes)
+            with jax.named_scope("lda.w_psum"):
+                dW_tot = jax.lax.psum(deltas[1][0], daxes)
             if lay is None:
                 D, W = counts
                 D_new = D[0] + deltas[0][0]
@@ -826,11 +835,12 @@ class _StreamedDistMixin:
         for _ in range(int(n_iters)):
             ss, _n_surv, sums = self._stream_epoch(ss)
             rows.append(sums / denom)
-        m = np.asarray(rows, np.float32).reshape(-1, 4)
+        m = np.asarray(rows, np.float32).reshape(-1, 5)
         stats = three_branch.ThreeBranchStats(
             frac_skipped=m[:, 0], frac_m_final=m[:, 1],
             frac_unchanged=m[:, 2], frac_at_max=m[:, 3],
-            frac_q_branch=np.zeros(len(rows), np.float32))
+            frac_q_branch=np.zeros(len(rows), np.float32),
+            frac_phase2_slots=m[:, 4])
         return ss, stats
 
 
@@ -939,7 +949,8 @@ class DistLDATrainer(_StreamedDistMixin):
                 W_head=P(None, None),
                 W_tail=tuple(P(None, None) for _ in self.layout.tail_caps),
                 overflow=P(), key=P(), iteration=P())
-        stats_spec = three_branch.ThreeBranchStats(P(), P(), P(), P(), P())
+        stats_spec = three_branch.ThreeBranchStats(P(), P(), P(), P(), P(),
+                                                   P())
         step = functools.partial(
             _dist_step, cfg=config, data_axes=daxes, model_axis="model",
             n_words=corpus.n_words, m_local=self.sc.m_local, g=config.g,
@@ -1226,7 +1237,7 @@ class _PSEpochCarry:
     start_topics: np.ndarray       # (R·L,) int32 epoch-start copy
     n_surv: float = 0.0
     stat_sums: np.ndarray = dataclasses.field(
-        default_factory=lambda: np.zeros(4, np.float64))
+        default_factory=lambda: np.zeros(5, np.float64))
 
 
 @dataclasses.dataclass
@@ -1462,17 +1473,19 @@ class PSDistTrainer:
 
             old_rel, w_old = _blk(topics)
             t_rel, w_new = _blk(new_topics)
-            dD_new = dD.at[doc_r, old_rel].add(-w_old) \
-                       .at[doc_r, t_rel].add(w_new)
-            dw_page = jnp.zeros((P_rows, K), jnp.int32) \
-                .at[word_rel, old_rel].add(-w_old) \
-                .at[word_rel, t_rel].add(w_new)
+            with jax.named_scope("lda.count_update"):
+                dD_new = dD.at[doc_r, old_rel].add(-w_old) \
+                           .at[doc_r, t_rel].add(w_new)
+                dw_page = jnp.zeros((P_rows, K), jnp.int32) \
+                    .at[word_rel, old_rel].add(-w_old) \
+                    .at[word_rel, t_rel].add(w_new)
             fmask = mask_r.astype(jnp.float32)
             sums = jnp.stack([
                 jnp.sum(skip.astype(jnp.float32) * fmask),
                 jnp.sum((skip | in_m).astype(jnp.float32) * fmask),
                 jnp.sum((new_topics == topics).astype(jnp.float32) * fmask),
-                jnp.sum((new_topics == k1).astype(jnp.float32) * fmask)])
+                jnp.sum((new_topics == k1).astype(jnp.float32) * fmask),
+                jnp.sum(fmask)])                  # every token is drawn
             n_surv = jnp.sum((~skip).astype(jnp.float32) * fmask)
             return new_topics, dD_new, dw_page, n_surv, sums
 
@@ -1600,7 +1613,7 @@ class PSDistTrainer:
             ss.d_blocks[w], ov = self._get_close()(ss.d_blocks[w], ep.dD)
             ss.overflow += int(ov)
         acc = ss.stat_rounds.setdefault(
-            clock, [0.0, np.zeros(4, np.float64)])
+            clock, [0.0, np.zeros(5, np.float64)])
         acc[0] += ep.n_surv
         acc[1] = acc[1] + ep.stat_sums
         ss.epochs[w] = None
@@ -1662,11 +1675,12 @@ class PSDistTrainer:
             rows.append(sums / denom)
         for c in [c for c in ss.stat_rounds if c < target]:
             del ss.stat_rounds[c]          # rounds reported by run_shards
-        m = np.asarray(rows, np.float32).reshape(-1, 4)
+        m = np.asarray(rows, np.float32).reshape(-1, 5)
         stats = three_branch.ThreeBranchStats(
             frac_skipped=m[:, 0], frac_m_final=m[:, 1],
             frac_unchanged=m[:, 2], frac_at_max=m[:, 3],
-            frac_q_branch=np.zeros(len(rows), np.float32))
+            frac_q_branch=np.zeros(len(rows), np.float32),
+            frac_phase2_slots=m[:, 4])
         return ss, stats
 
     def run_shards(self, ss: PSStreamState, n_shards: int = 1):
@@ -1771,9 +1785,11 @@ class PSDistTrainer:
             dD_np = np.zeros((self.sc.m_local, self.cfg.n_topics),
                              np.int32)
             client = ss.clients[w]
+            drawn = 0
             for r in range(cur):
                 cols = slice(r * L, (r + 1) * L)
                 m = self._st_mask[w, cols] > 0
+                drawn += int(m.sum())
                 old = ep.start_topics[cols][m]
                 new = done[cols][m]
                 doc = self._st_doc[w, cols][m]
@@ -1788,7 +1804,12 @@ class PSDistTrainer:
                 client.push_page(base, base + self._page_rows, dw)
             ep.dD = ep.dD + jnp.asarray(dD_np)
             if ext.stat_sums is not None:
-                ep.stat_sums = ext.stat_sums[w].copy()
+                row = ext.stat_sums[w]
+                if row.shape[0] == 4:
+                    # saved before the phase-2 slot sum was kept: every
+                    # real token of the done sub-shards was drawn
+                    row = np.append(row, float(drawn))
+                ep.stat_sums = row.copy()
                 ep.n_surv = float(ext.n_surv[w])
         if off != ext.done_topics.shape[0]:
             raise ValueError(

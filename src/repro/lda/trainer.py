@@ -26,7 +26,7 @@ from repro.core import esca, llpt as llpt_mod
 from repro.lda import invariants
 from repro.lda.corpus import Corpus, pad_corpus
 from repro.lda.model import LDAConfig, LDAState
-from repro.runtime import chaos
+from repro.runtime import chaos, compiles
 
 __all__ = ["LDATrainer", "chunk_to_boundary", "run_boundary_chunked"]
 
@@ -71,39 +71,52 @@ def run_boundary_chunked(n_iters: int, start_iter: int, *, n_tokens: int,
     time — the fit supervisor's straggler detector rides here without
     changing the chunking or paying extra host syncs. Being the one
     driver, this is also where step-indexed chaos faults fire.
+
+    Each chunk runs inside an ``lda.chunk`` step span on the profiler's
+    clock, with ``lda.eval``, ``lda.stats`` and ``lda.checkpoint`` spans
+    inside it; a program lowered after the first chunk is named through
+    ``log_fn``.
     """
     history: dict[str, list] = {"iteration": [], "llpt": [],
                                 "tokens_per_sec": [], "stats": []}
+    late = compiles.LateLowerings(log_fn)
     done = 0
     while done < n_iters:
         chunk = chunk_to_boundary(start_iter + done, done, n_iters - done,
                                   eval_every, checkpoint_every)
-        t0 = time.perf_counter()
-        # inside the timed window: an injected slow step shows up in its
-        # own chunk's wall time (the straggler detector's test surface)
-        if chaos.armed():
-            chaos.step_range(start_iter + done, chunk)
-        stats = run_chunk(chunk)
-        dt = time.perf_counter() - t0
-        done += chunk
-        it = start_iter + done
-        if on_chunk is not None:
-            on_chunk(it, chunk, dt)
-        if it % eval_every == 0 or done == chunk:
-            score = evaluate()
-            last = {k: float(np.asarray(v)[-1])
-                    for k, v in stats._asdict().items()}
-            history["iteration"].append(it)
-            history["llpt"].append(score)
-            history["tokens_per_sec"].append(n_tokens * chunk / dt)
-            history["stats"].append(last)
-            if log_fn:
-                log_fn(f"iter={it:4d} llpt={score:+.4f} "
-                       f"tok/s={n_tokens*chunk/dt:,.0f} "
-                       f"unchanged={last.get('frac_unchanged', 0):.3f}")
-        if checkpoint_every and save is not None \
-                and it % checkpoint_every == 0:
-            save(it)
+        with jax.profiler.StepTraceAnnotation("lda.chunk",
+                                              step_num=start_iter + done):
+            t0 = time.perf_counter()
+            # inside the timed window: an injected slow step shows up in
+            # its own chunk's wall time (the straggler detector's test
+            # surface)
+            if chaos.armed():
+                chaos.step_range(start_iter + done, chunk)
+            stats = run_chunk(chunk)
+            dt = time.perf_counter() - t0
+            done += chunk
+            it = start_iter + done
+            if on_chunk is not None:
+                on_chunk(it, chunk, dt)
+            if it % eval_every == 0 or done == chunk:
+                with jax.profiler.TraceAnnotation("lda.eval"):
+                    score = evaluate()
+                with jax.profiler.TraceAnnotation("lda.stats"):
+                    last = {k: float(np.asarray(v)[-1])
+                            for k, v in stats._asdict().items()}
+                history["iteration"].append(it)
+                history["llpt"].append(score)
+                history["tokens_per_sec"].append(n_tokens * chunk / dt)
+                history["stats"].append(last)
+                if log_fn:
+                    log_fn(f"iter={it:4d} llpt={score:+.4f} "
+                           f"tok/s={n_tokens*chunk/dt:,.0f} "
+                           f"unchanged={last.get('frac_unchanged', 0):.3f}")
+            if checkpoint_every and save is not None \
+                    and it % checkpoint_every == 0:
+                with jax.profiler.TraceAnnotation("lda.checkpoint"):
+                    save(it)
+        late.settle(it)
     return history
 
 
@@ -447,7 +460,8 @@ class LDATrainer:
             carry["fs"], stats, _ = pipe.run_fused(carry["fs"], chunk)
             jax.block_until_ready(carry["fs"].topics)
             if selfcheck:
-                pipe.selfcheck(carry["fs"])
+                with jax.profiler.TraceAnnotation("lda.selfcheck"):
+                    pipe.selfcheck(carry["fs"])
             return stats
 
         if self.residency == "disk":
@@ -539,33 +553,53 @@ class LDATrainer:
 
     def _run_stepwise(self, state, history, start_iter, n_iters, live,
                       log_fn, checkpoint_every, on_chunk):
+        """One ``step()`` per iteration, each inside an ``lda.iteration``
+        step span on the profiler's clock, with ``lda.selfcheck``,
+        ``lda.eval``, ``lda.stats`` (the stats' device-to-host pull) and
+        ``lda.checkpoint`` spans inside it. A program lowered after the
+        first iteration is named through ``log_fn``."""
+        late = compiles.LateLowerings(log_fn)
         for i in range(start_iter, start_iter + n_iters):
-            t0 = time.perf_counter()
-            if chaos.armed():
-                chaos.step_range(i, 1)
-            state, stats = self.step(state)
-            live["state"] = state
-            jax.block_until_ready(state.topics)
-            dt = time.perf_counter() - t0
-            if self.config.selfcheck:
+            with jax.profiler.StepTraceAnnotation("lda.iteration",
+                                                  step_num=i):
+                state = self._stepwise_iteration(
+                    state, history, i, start_iter, live, log_fn,
+                    checkpoint_every, on_chunk)
+            late.settle(i + 1)
+        return state, history
+
+    def _stepwise_iteration(self, state, history, i, start_iter, live,
+                            log_fn, checkpoint_every, on_chunk):
+        t0 = time.perf_counter()
+        if chaos.armed():
+            chaos.step_range(i, 1)
+        state, stats = self.step(state)
+        live["state"] = state
+        jax.block_until_ready(state.topics)
+        dt = time.perf_counter() - t0
+        if self.config.selfcheck:
+            with jax.profiler.TraceAnnotation("lda.selfcheck"):
                 invariants.check_dense_counts(
                     state.D, state.W, n_tokens=self.corpus.n_tokens,
                     where=f"step (iteration {i + 1})")
-            if on_chunk is not None:
-                on_chunk(i + 1, 1, dt)
-            if (i + 1) % self.config.eval_every == 0 or i == start_iter:
+        if on_chunk is not None:
+            on_chunk(i + 1, 1, dt)
+        if (i + 1) % self.config.eval_every == 0 or i == start_iter:
+            with jax.profiler.TraceAnnotation("lda.eval"):
                 score = self.evaluate(state)
-                history["iteration"].append(i + 1)
-                history["llpt"].append(score)
-                history["tokens_per_sec"].append(self.corpus.n_tokens / dt)
-                history["stats"].append(
-                    {k: float(np.asarray(v)) for k, v in stats.items()})
-                if log_fn:
-                    log_fn(f"iter={i+1:4d} llpt={score:+.4f} "
-                           f"tok/s={self.corpus.n_tokens/dt:,.0f} "
-                           f"unchanged={history['stats'][-1].get('frac_unchanged', 0):.3f}")
-            if (checkpoint_every and self.checkpoint_manager is not None
-                    and (i + 1) % checkpoint_every == 0):
+            with jax.profiler.TraceAnnotation("lda.stats"):
+                last = {k: float(np.asarray(v)) for k, v in stats.items()}
+            history["iteration"].append(i + 1)
+            history["llpt"].append(score)
+            history["tokens_per_sec"].append(self.corpus.n_tokens / dt)
+            history["stats"].append(last)
+            if log_fn:
+                log_fn(f"iter={i+1:4d} llpt={score:+.4f} "
+                       f"tok/s={self.corpus.n_tokens/dt:,.0f} "
+                       f"unchanged={last.get('frac_unchanged', 0):.3f}")
+        if (checkpoint_every and self.checkpoint_manager is not None
+                and (i + 1) % checkpoint_every == 0):
+            with jax.profiler.TraceAnnotation("lda.checkpoint"):
                 self.checkpoint_manager.save(int(state.iteration),
                                              state.host_payload())
-        return state, history
+        return state

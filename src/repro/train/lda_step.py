@@ -134,32 +134,35 @@ def scatter_changed_deltas(topics, new_topics, doc_ids, word_ids, mask, *,
     ``D``/``W`` may be the live count matrices (dense pipeline) or zero
     delta matrices destined for a packed repack (hybrid pipeline); the
     chunk bodies are cond-guarded so chunks past the changed-token tail
-    cost one predicate.
+    cost one predicate. Its operations carry the ``lda.count_update``
+    scope in the program's op metadata, the phase being no program of its
+    own inside the fused, compacted and streamed programs.
     """
-    n = topics.shape[0]
-    changed = (new_topics != topics) & (mask > 0)
-    rank_c = jnp.cumsum(changed) - 1
-    n_chg = (rank_c[-1] + 1).astype(jnp.int32)
-    n_chunks = max(1, -(-n // capacity))
-    chg_idx = three_branch.compact_survivor_indices(
-        rank_c, ~changed, n_chunks * capacity)
+    with jax.named_scope("lda.count_update"):
+        n = topics.shape[0]
+        changed = (new_topics != topics) & (mask > 0)
+        rank_c = jnp.cumsum(changed) - 1
+        n_chg = (rank_c[-1] + 1).astype(jnp.int32)
+        n_chunks = max(1, -(-n // capacity))
+        chg_idx = three_branch.compact_survivor_indices(
+            rank_c, ~changed, n_chunks * capacity)
 
-    def upd_body(c, carry):
-        def run_chunk(carry):
-            D, W, colsum = carry
-            idx = jax.lax.dynamic_slice(chg_idx, (c * capacity,),
-                                        (capacity,))
-            w = (idx < n).astype(jnp.int32)   # sentinel slots add 0
-            d_c, v_c = doc_ids[idx], word_ids[idx]
-            old_c, new_c = topics[idx], new_topics[idx]
-            D = D.at[d_c, old_c].add(-w).at[d_c, new_c].add(w)
-            W = W.at[v_c, old_c].add(-w).at[v_c, new_c].add(w)
-            colsum = colsum.at[old_c].add(-w).at[new_c].add(w)
-            return D, W, colsum
-        return jax.lax.cond(c * capacity < n_chg, run_chunk,
-                            lambda carry: carry, carry)
+        def upd_body(c, carry):
+            def run_chunk(carry):
+                D, W, colsum = carry
+                idx = jax.lax.dynamic_slice(chg_idx, (c * capacity,),
+                                            (capacity,))
+                w = (idx < n).astype(jnp.int32)   # sentinel slots add 0
+                d_c, v_c = doc_ids[idx], word_ids[idx]
+                old_c, new_c = topics[idx], new_topics[idx]
+                D = D.at[d_c, old_c].add(-w).at[d_c, new_c].add(w)
+                W = W.at[v_c, old_c].add(-w).at[v_c, new_c].add(w)
+                colsum = colsum.at[old_c].add(-w).at[new_c].add(w)
+                return D, W, colsum
+            return jax.lax.cond(c * capacity < n_chg, run_chunk,
+                                lambda carry: carry, carry)
 
-    return jax.lax.fori_loop(0, n_chunks, upd_body, (D, W, colsum))
+        return jax.lax.fori_loop(0, n_chunks, upd_body, (D, W, colsum))
 
 
 def build_warp_proposal(W, colsum, beta: float):
@@ -197,14 +200,17 @@ def warp_stats(mask, acc_any, new_topics, old_topics,
         n_proposals=jnp.float32(2 * n_cycles))
 
 
-def branch_stats(skip, in_m_acc, new_topics, old_topics, k1):
-    """The ThreeBranchStats both pipelines report (Fig 12 fractions)."""
+def branch_stats(skip, in_m_acc, new_topics, old_topics, k1, slots):
+    """The ThreeBranchStats both pipelines report (Fig 12 fractions);
+    ``slots`` is the exact-draw slots phase 2 ran (``chunk_slots``)."""
     f32 = jnp.float32
+    n = skip.shape[0]
     return three_branch.ThreeBranchStats(
         frac_skipped=jnp.mean(skip.astype(f32)),
         frac_m_final=jnp.mean((skip | in_m_acc).astype(f32)),
         frac_unchanged=jnp.mean((new_topics == old_topics).astype(f32)),
         frac_at_max=jnp.mean((new_topics == k1).astype(f32)),
+        frac_phase2_slots=jnp.minimum(slots.astype(f32) / max(n, 1), 1.0),
     )
 
 
@@ -689,7 +695,8 @@ class FusedPipeline:
         D, W, colsum = scatter_changed_deltas(
             topics, new_topics, doc_ids, word_ids, mask,
             capacity=capacity, D=D, W=W, colsum=colsum)
-        st = branch_stats(dec.skip, in_m_acc, new_topics, topics, dec.k1)
+        st = branch_stats(dec.skip, in_m_acc, new_topics, topics, dec.k1,
+                          three_branch.chunk_slots(n_surv, capacity, n))
         new_state = FusedState(topics=new_topics, D=D, W=W, colsum=colsum,
                                key=key, iteration=iteration + 1)
         return new_state, st, n_surv, max_span
@@ -1009,6 +1016,7 @@ class HybridFusedPipeline(FusedPipeline):
         new_topics = dec.k1                      # skipped ⇒ K1 everywhere
         in_m_acc = jnp.zeros(n, jnp.bool_)
         n_surv_total = jnp.int32(0)
+        slots = jnp.int32(0)
         max_span = jnp.int32(0)
         for seg_mask, n_seg, chunk_fn in segments:
             if n_seg == 0:
@@ -1028,6 +1036,7 @@ class HybridFusedPipeline(FusedPipeline):
                 capacity=capacity, n_chunks=n_chunks, sample_chunk=chunk_fn)
             in_m_acc = in_m_acc | in_m_seg
             n_surv_total = n_surv_total + n_surv
+            slots = slots + three_branch.chunk_slots(n_surv, capacity, n_seg)
 
         # -- the update: the SAME compacted changed-token scatter engine as
         # the dense pipeline, aimed straight at the densified matrices
@@ -1040,7 +1049,8 @@ class HybridFusedPipeline(FusedPipeline):
         d_packed, w_head, w_tail, overflow = self._repack_counts(
             d_new, w_new, overflow)
 
-        st = branch_stats(dec.skip, in_m_acc, new_topics, topics, dec.k1)
+        st = branch_stats(dec.skip, in_m_acc, new_topics, topics, dec.k1,
+                          slots)
         from repro.lda.model import SparseLDAState
         new_state = SparseLDAState(
             topics=new_topics, D=d_packed, W_head=w_head, W_tail=w_tail,
@@ -1275,7 +1285,7 @@ class _EpochCarry:
     n_surv: int = 0
     max_span: int = 0
     stat_sums: np.ndarray = dataclasses.field(
-        default_factory=lambda: np.zeros(4, np.float64))
+        default_factory=lambda: np.zeros(5, np.float64))
 
     def flush_stats(self) -> None:
         for n_surv, span, sums in self.stats_parts:
@@ -1635,8 +1645,9 @@ class StreamingPipeline(FusedPipeline):
                     capacity=capacity, D=deltas[0],
                     W=jnp.zeros((P, cfg.n_topics), jnp.int32),
                     colsum=deltas[1])
-                sums = _shard_stat_sums(lo, n, dec, in_m, new_topics,
-                                        topics_s)
+                sums = _shard_stat_sums(
+                    lo, n, dec, in_m, new_topics, topics_s,
+                    three_branch.chunk_slots(n_surv, capacity, L))
                 return (new_topics, (dD, dcs), dw_win, n_surv,
                         jnp.int32(0), sums)
 
@@ -1666,7 +1677,9 @@ class StreamingPipeline(FusedPipeline):
                 topics_s, new_topics, doc_s, word_s, mask_s,
                 capacity=capacity, D=deltas[0], W=deltas[1],
                 colsum=deltas[2])
-            sums = _shard_stat_sums(lo, n, dec, in_m, new_topics, topics_s)
+            sums = _shard_stat_sums(
+                lo, n, dec, in_m, new_topics, topics_s,
+                three_branch.chunk_slots(n_surv, capacity, L))
             return new_topics, deltas, n_surv, max_span, sums
 
         fn = jax.jit(shard_fn, donate_argnums=(2, 8))
@@ -1940,11 +1953,12 @@ class StreamingPipeline(FusedPipeline):
             self.note_survivors(np.asarray(surv_rows, np.float64))
             if self.balance == "tiles":
                 self.note_spans(span_rows)
-        m = np.asarray(mean_rows, np.float32).reshape(-1, 4)
+        m = np.asarray(mean_rows, np.float32).reshape(-1, 5)
         stats = three_branch.ThreeBranchStats(
             frac_skipped=m[:, 0], frac_m_final=m[:, 1],
             frac_unchanged=m[:, 2], frac_at_max=m[:, 3],
-            frac_q_branch=np.zeros(m.shape[0], np.float32))
+            frac_q_branch=np.zeros(m.shape[0], np.float32),
+            frac_phase2_slots=np.minimum(m[:, 4], 1.0))
         return ss, stats, np.asarray(surv_rows, np.int64)
 
     # -- measured memory ----------------------------------------------------
@@ -2177,10 +2191,11 @@ class StreamingPipeline(FusedPipeline):
                                         jnp.asarray(mask)))
 
 
-def _shard_stat_sums(lo, n, dec, in_m, new_topics, old_topics):
+def _shard_stat_sums(lo, n, dec, in_m, new_topics, old_topics, slots):
     """Per-shard stat SUMS over slots that exist in the resident stream
     (global index < n), so the epoch totals divide to the same fractions
-    the resident pipeline reports."""
+    the resident pipeline reports; the last is the exact-draw slots phase
+    2 ran in the shard (``chunk_slots``)."""
     L = new_topics.shape[0]
     valid = (lo + jnp.arange(L)) < n
     f32 = jnp.float32
@@ -2189,7 +2204,8 @@ def _shard_stat_sums(lo, n, dec, in_m, new_topics, old_topics):
         return jnp.sum(jnp.where(valid, x, False).astype(f32))
 
     return jnp.stack([s(dec.skip), s(dec.skip | in_m),
-                      s(new_topics == old_topics), s(new_topics == dec.k1)])
+                      s(new_topics == old_topics), s(new_topics == dec.k1),
+                      slots.astype(f32)])
 
 
 class StreamingHybridPipeline(StreamingPipeline):
@@ -2430,6 +2446,7 @@ class StreamingHybridPipeline(StreamingPipeline):
                 new_topics = dec.k1
                 in_m_acc = jnp.zeros(L, jnp.bool_)
                 n_surv_total = jnp.int32(0)
+                slots = jnp.int32(0)
                 for seg_mask, chunk_fn in segments:
                     skip_seg = dec.skip if seg_mask is None \
                         else dec.skip | ~seg_mask
@@ -2441,13 +2458,15 @@ class StreamingHybridPipeline(StreamingPipeline):
                         n_chunks=n_chunks, sample_chunk=chunk_fn)
                     in_m_acc = in_m_acc | in_m_seg
                     n_surv_total = n_surv_total + n_surv
+                    slots = slots + three_branch.chunk_slots(
+                        n_surv, capacity, L)
                 dD, dw_win, dcs = scatter_changed_deltas(
                     topics_s, new_topics, doc_s, word_l, mask_s,
                     capacity=capacity, D=deltas[0],
                     W=jnp.zeros((P, cfg.n_topics), jnp.int32),
                     colsum=deltas[1])
                 sums = _shard_stat_sums(lo, n, dec, in_m_acc, new_topics,
-                                        topics_s)
+                                        topics_s, slots)
                 return (new_topics, (dD, dcs), dw_win, n_surv_total,
                         jnp.int32(0), sums)
 
@@ -2479,6 +2498,7 @@ class StreamingHybridPipeline(StreamingPipeline):
             new_topics = dec.k1
             in_m_acc = jnp.zeros(L, jnp.bool_)
             n_surv_total = jnp.int32(0)
+            slots = jnp.int32(0)
             max_span = jnp.int32(0)
             for seg_mask, chunk_fn in segments:
                 skip_seg = dec.skip if seg_mask is None \
@@ -2495,12 +2515,14 @@ class StreamingHybridPipeline(StreamingPipeline):
                     n_chunks=n_chunks, sample_chunk=chunk_fn)
                 in_m_acc = in_m_acc | in_m_seg
                 n_surv_total = n_surv_total + n_surv
+                slots = slots + three_branch.chunk_slots(
+                    n_surv, capacity, L)
             deltas = scatter_changed_deltas(
                 topics_s, new_topics, doc_s, word_s, mask_s,
                 capacity=capacity, D=deltas[0], W=deltas[1],
                 colsum=deltas[2])
             sums = _shard_stat_sums(lo, n, dec, in_m_acc, new_topics,
-                                    topics_s)
+                                    topics_s, slots)
             return new_topics, deltas, n_surv_total, max_span, sums
 
         fn = jax.jit(shard_fn, donate_argnums=(2, 8))

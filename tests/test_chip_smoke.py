@@ -75,7 +75,7 @@ def test_one_chip_path_at_toy_size(monkeypatch, capsys):
         return False, 1
 
     monkeypatch.setattr(smoke, "kernel_evidence", cpu_evidence)
-    device = smoke.one_chip(smoke.CompileClock())
+    device = smoke.one_chip()
     assert device == {"platform": "cpu", "kind": "cpu", "count": 1}
     assert seen == [(True, 0), (True, 0)]
     out = capsys.readouterr().out
@@ -103,7 +103,7 @@ smoke.device_phase = lambda want: {"platform": "cpu", "kind": "cpu",
                                    "count": want}
 smoke.fits_phase = lambda need, devices: None
 smoke.mem_stat = lambda dev, key="": 1
-print(json.dumps(smoke.four_chips(smoke.CompileClock())))
+print(json.dumps(smoke.four_chips()))
 """
 
 
