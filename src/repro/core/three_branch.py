@@ -30,10 +30,11 @@ Implementation notes (TPU adaptation, DESIGN.md §2):
     computed once per word as V-vectors and gathered per token — the paper's
     "once per word" amortization without warp cooperation;
   * K1/K2 are pair-packed into one int32 exactly as the paper stores them;
-  * the exact (un-skipped) branch is O(K) per token here (dense reference);
-    the compacted path (``capacity=...``) gathers survivors into fixed-size
-    chunks so the saved work is real, mirroring the paper's shrinking
-    workload; kernels/ carries the fused Pallas version.
+  * the exact (un-skipped) branch is O(K) per token here. The default
+    sampler gathers survivors into fixed-size chunks when few enough
+    survive, so the saved work is real, mirroring the paper's shrinking
+    workload, and draws every token otherwise (``_sample_adaptive``);
+    kernels/ carries the fused Pallas version.
 """
 
 from __future__ import annotations
@@ -44,7 +45,6 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.core import esca
 from repro.core.sparse import pack_pairs
@@ -52,7 +52,7 @@ from repro.core.sparse import pack_pairs
 __all__ = [
     "WordStats", "word_stats", "SkipDecision", "skip_phase",
     "exact_three_branch", "exact_three_branch_tiled", "ThreeBranchStats",
-    "chunk_slots", "sample",
+    "chunk_slots", "sample", "derived_capacity",
     "build_plan", "Plan", "survivor_rank", "compact_survivor_indices",
     "map_token_tiles", "run_survivor_chunks",
 ]
@@ -221,6 +221,9 @@ class ThreeBranchStats(NamedTuple):
     # Every sampler in the package computes it; the NaN default only
     # marks a stats tuple built elsewhere as "not counted".
     frac_phase2_slots: jax.Array | float = float("nan")
+    # 1.0 where the stepwise sampler ran phase 2 over compacted survivor
+    # chunks, 0.0 where it drew every token (``_sample_adaptive``)
+    phase2_compacted: jax.Array | float = 0.0
 
 
 def chunk_slots(n_surv, capacity: int, n_slots: int):
@@ -231,19 +234,48 @@ def chunk_slots(n_surv, capacity: int, n_slots: int):
     return jnp.minimum(n_run * capacity, n_slots).astype(jnp.int32)
 
 
+# Survivor chunks at full survivorship under a derived capacity.
+TARGET_CHUNKS = 64
+# The derived plan runs phase 2 over survivor chunks when their estimated
+# slots are under this share of the tokens, and draws every token
+# otherwise: the break-even of the two on a TPU v5e (PERF.md §6).
+COMPACT_BELOW = 0.69
+# Tokens whose skip test estimates the survivor share before the branch.
+ESTIMATE_TOKENS = 1 << 16
+
+
 @dataclasses.dataclass(frozen=True)
 class Plan:
     """Static sampling plan (built once per corpus/config)."""
     g: int
     tile_size: int
     capacity: int | None          # survivor-chunk capacity; None = reference
+    # draw over survivor chunks when their estimated slots are under this
+    # share of the tokens, else every token; None always takes the chunks
+    # (a pinned capacity)
+    compact_below: float | None = None
+
+
+def derived_capacity(n_tokens: int, tile_size: int) -> int:
+    """Survivor-chunk capacity for ``n_tokens``: whole tiles, so every
+    ``lax.map`` tile keeps the reference's shape, about ``TARGET_CHUNKS``
+    chunks when every token survives, and at most ``n_tokens``."""
+    cap = tile_size * -(-n_tokens // (TARGET_CHUNKS * tile_size))
+    return max(1, min(cap, n_tokens))
 
 
 def build_plan(corpus, config) -> Plan:
-    cap = None
-    if getattr(config, "survivor_capacity", None):
-        cap = int(config.survivor_capacity)
-    return Plan(g=config.g, tile_size=config.tile_size, capacity=cap)
+    """An explicit ``survivor_capacity`` pins the chunk capacity and always
+    compacts; unset, the capacity is derived from the padded corpus and
+    the sampler picks its phase 2 from each iteration's survivors."""
+    if config.survivor_capacity:
+        return Plan(g=config.g, tile_size=config.tile_size,
+                    capacity=int(config.survivor_capacity))
+    tile = config.tile_size
+    n = -(-corpus.n_tokens // tile) * tile      # the trainer's padded count
+    return Plan(g=config.g, tile_size=tile,
+                capacity=derived_capacity(n, tile),
+                compact_below=COMPACT_BELOW)
 
 
 @functools.partial(jax.jit, static_argnames=("g", "alpha", "tile_size"))
@@ -251,13 +283,12 @@ def _sample_reference(key, word_ids, doc_ids, old_topics, D, W_hat,
                       *, g, alpha, tile_size):
     """Reference path: phase 1 for stats + exact phase 2 for *all* tokens.
 
-    Identical output distribution to the compacted path (same u per token);
-    used as the oracle and for small problems. One program
-    (``jit__sample_reference``): its phases carry the nested programs'
-    names in the op metadata (``jit(word_stats)``, ``jit(skip_phase)``,
-    ``jit(exact_three_branch)``). As programs of their own the skip test
-    and phase 2 took 58% longer on a TPU v5e than together, so they stay
-    together.
+    Identical output to the compacted path (same u per token); the
+    oracle, and the adaptive sampler's dense branch as it stands: on a
+    TPU v5e this program took 8.2 s at 25 M tokens where the same work
+    arranged otherwise took 13 s (PERF.md §6). Its phases carry the
+    nested programs' names in the op metadata (``jit(word_stats)``,
+    ``jit(skip_phase)``, ``jit(exact_three_branch)``).
     """
     stats_w = word_stats(W_hat, g=g, alpha=alpha)
     n = word_ids.shape[0]
@@ -273,7 +304,8 @@ def _sample_reference(key, word_ids, doc_ids, old_topics, D, W_hat,
         frac_m_final=jnp.mean(in_m.astype(jnp.float32)),
         frac_unchanged=jnp.mean((new_topics == old_topics).astype(jnp.float32)),
         frac_at_max=jnp.mean((new_topics == dec.k1).astype(jnp.float32)),
-        frac_phase2_slots=None,     # no output: ``sample`` sets it
+        frac_phase2_slots=jnp.float32(1.0),     # every token is drawn
+        phase2_compacted=jnp.float32(0.0),
     )
     return new_topics, st
 
@@ -389,29 +421,71 @@ def _sample_compacted(key, word_ids, doc_ids, old_topics, D, W_hat,
         frac_at_max=jnp.mean((new_topics == dec.k1).astype(jnp.float32)),
         frac_phase2_slots=chunk_slots(n_surv, capacity, n).astype(
             jnp.float32) / max(n, 1),
+        phase2_compacted=jnp.float32(1.0),
     )
     return new_topics, st
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "g", "alpha", "capacity", "tile_size", "compact_below"))
+def _sample_adaptive(key, word_ids, doc_ids, old_topics, D, W_hat,
+                     *, g, alpha, capacity, tile_size, compact_below):
+    """The sampler as ONE sync-free dispatch that picks its phase 2 on the
+    device from this iteration's survivors.
+
+    The skip test over every ``n // ESTIMATE_TOKENS``-th token, with the
+    iteration's own uniforms, estimates the share of chunk slots the
+    survivors fill; ``lax.cond`` then runs ``_sample_compacted`` when that
+    is under ``compact_below`` and ``_sample_reference`` otherwise.
+    Ranking, scattering and gathering near-full survivor sets costs more
+    than the draws it saves. Each branch is its program as it stands, word
+    stats and skip test included: the reference's schedule is what makes
+    it fast on a TPU v5e, and the estimate costs a small fraction of
+    either. Both draw each token from the same ``u`` with the same
+    arithmetic, so the topics equal ``_sample_reference``'s bit for bit,
+    whichever branch runs.
+    """
+    n = word_ids.shape[0]
+    stride = max(1, n // ESTIMATE_TOKENS)
+    stats_w = word_stats(W_hat, g=g, alpha=alpha)
+    u = jax.random.uniform(key, (n,), dtype=jnp.float32)
+    dec = skip_phase(u[::stride], word_ids[::stride], doc_ids[::stride], D,
+                     stats_w, g=g, alpha=alpha)
+    est_surv = jnp.round(jnp.mean((~dec.skip).astype(jnp.float32)) * n)
+    est_slots = chunk_slots(est_surv.astype(jnp.int32), capacity, n)
+    compact = est_slots.astype(jnp.float32) < jnp.float32(compact_below * n)
+    args = (key, word_ids, doc_ids, old_topics, D, W_hat)
+    return jax.lax.cond(
+        compact,
+        lambda a: _sample_compacted(*a, g=g, alpha=alpha, capacity=capacity,
+                                    tile_size=tile_size),
+        lambda a: _sample_reference(*a, g=g, alpha=alpha,
+                                    tile_size=tile_size),
+        args)
 
 
 def sample(key, plan: Plan, word_ids, doc_ids, old_topics, D, W, config):
     """Full EZLDA sampler: Ŵ, phase 1, (compacted) phase 2, stats.
 
-    With ``plan.capacity`` set, only ceil(survivors/capacity) chunks of exact
-    sampling run — the paper's workload reduction made shape-static — and
-    the whole sampler is a single sync-free dispatch (see _sample_compacted;
-    train/lda_step.py builds its fused scanned iteration on the same
-    machinery).
+    The derived plan (``build_plan``) runs ``_sample_adaptive``: exact
+    sampling over ceil(survivors/capacity) chunks whenever that is the
+    cheaper phase 2, over every token otherwise, in one sync-free
+    dispatch. A pinned capacity always runs the chunks
+    (``_sample_compacted``; train/lda_step.py builds its fused scanned
+    iteration on the same machinery); ``capacity=None`` is the reference,
+    which draws every token.
     """
     alpha, beta = config.alpha_, config.beta
     W_hat = esca.compute_w_hat(W, beta)
     if plan.capacity is None:
-        new_topics, st = _sample_reference(
+        return _sample_reference(
             key, word_ids, doc_ids, old_topics, D, W_hat, g=plan.g,
             alpha=alpha, tile_size=plan.tile_size)
-        # every token is drawn. Set outside the program so its outputs,
-        # and so its compiled form, stay as they were: on a TPU v5e small
-        # changes to this program's outputs moved its time by up to 60%
-        return new_topics, st._replace(frac_phase2_slots=np.float32(1.0))
-    return _sample_compacted(key, word_ids, doc_ids, old_topics, D, W_hat,
-                             g=plan.g, alpha=alpha, capacity=plan.capacity,
-                             tile_size=plan.tile_size)
+    if plan.compact_below is None:
+        return _sample_compacted(
+            key, word_ids, doc_ids, old_topics, D, W_hat, g=plan.g,
+            alpha=alpha, capacity=plan.capacity, tile_size=plan.tile_size)
+    return _sample_adaptive(key, word_ids, doc_ids, old_topics, D, W_hat,
+                            g=plan.g, alpha=alpha, capacity=plan.capacity,
+                            tile_size=plan.tile_size,
+                            compact_below=plan.compact_below)

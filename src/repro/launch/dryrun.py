@@ -202,7 +202,7 @@ def lower_lda(multi_pod: bool, n_topics: int = 1024, v: int = 65_536,
     tok_spec = P(daxes)
     state_specs = DistLDAState(topics=tok_spec, D=P(daxes, None, "model"),
                                W=P(None, "model"), key=P(), iteration=P())
-    stats_spec = ThreeBranchStats(P(), P(), P(), P(), P(), P())
+    stats_spec = ThreeBranchStats(*[P()] * len(ThreeBranchStats._fields))
     step = functools.partial(
         _dist_step, cfg=cfg, data_axes=daxes, model_axis="model",
         n_words=v, m_local=m_loc, g=cfg.g)

@@ -1058,7 +1058,7 @@ class LDAEngine:
                 mgr.save(it * (S + 1), pipe.stream_payload(ss))
                 if it % self.config.eval_every == 0 or first:
                     first = False
-                    last = {kk: float(np.asarray(v)[-1])
+                    last = {kk: float(np.ravel(v)[-1])
                             for kk, v in stats._asdict().items()}
                     n_tok = self._backend.trainer.n_real_tokens
                     merge_hist({"iteration": [it],

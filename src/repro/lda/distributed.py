@@ -949,8 +949,8 @@ class DistLDATrainer(_StreamedDistMixin):
                 W_head=P(None, None),
                 W_tail=tuple(P(None, None) for _ in self.layout.tail_caps),
                 overflow=P(), key=P(), iteration=P())
-        stats_spec = three_branch.ThreeBranchStats(P(), P(), P(), P(), P(),
-                                                   P())
+        stats_spec = three_branch.ThreeBranchStats(
+            *[P()] * len(three_branch.ThreeBranchStats._fields))
         step = functools.partial(
             _dist_step, cfg=config, data_axes=daxes, model_axis="model",
             n_words=corpus.n_words, m_local=self.sc.m_local, g=config.g,

@@ -142,7 +142,7 @@ class LDAConfig:
     tail_sampler: str = "exact"      # hybrid tail phase-2: "exact" | "sparse"
     balance: str = "none"            # workload balancing: "none" | "tiles"
     d_capacity: int | None = None    # packed-ELL D row capacity; None=auto
-    survivor_capacity: int | None = None  # phase-2 chunk size; None=reference
+    survivor_capacity: int | None = None  # phase-2 chunk size pin; None=derived
     dense_word_threshold: int | None = None  # tokens>=thr => dense W row; None=K (paper)
     fused: bool = False              # route run() through train/lda_step.py
     corpus_residency: str = "full"   # T: "full" | "streamed" | "auto" | "disk"

@@ -102,7 +102,7 @@ def run_boundary_chunked(n_iters: int, start_iter: int, *, n_tokens: int,
                 with jax.profiler.TraceAnnotation("lda.eval"):
                     score = evaluate()
                 with jax.profiler.TraceAnnotation("lda.stats"):
-                    last = {k: float(np.asarray(v)[-1])
+                    last = {k: float(np.ravel(v)[-1])
                             for k, v in stats._asdict().items()}
                 history["iteration"].append(it)
                 history["llpt"].append(score)

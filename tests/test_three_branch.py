@@ -6,14 +6,19 @@ The load-bearing properties:
      the distribution).
   3. Three-branch sampling induces exactly p ∝ (D[d]+α)∘Ŵ[v] (stratified-u
      total-variation check) — same distribution as two-branch.
-  4. The compacted (capacity) path is bit-identical to the reference path.
+  4. The default sampler, on either branch, and a pinned survivor
+     capacity are bit-identical to the reference path.
   5. End-to-end: LLPT rises; skip fraction grows over iterations (Fig 12b)
      and with g (paper parameter study).
 """
 
+import dataclasses
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from _hyp import given, settings, st
 
 from repro.core import esca, three_branch
@@ -120,24 +125,98 @@ def test_three_branch_matches_two_branch_distribution():
     assert 0.5 * np.abs(h2 - h3).sum() < 1e-3
 
 
-def test_compacted_path_equals_reference(small_corpus, small_config):
-    cfg = small_config
+@pytest.fixture(scope="module")
+def states(small_corpus):
+    """A fresh state and a trained one where most tokens skip, on a
+    trainer whose padded N (2,368) is not a multiple of the derived
+    capacity (48)."""
+    cfg = LDAConfig(n_topics=16, tile_size=16, eval_every=5)
     tr = LDATrainer(small_corpus, cfg, _from_engine=True)
-    state = tr.init_state()
-    for _ in range(3):
-        state, _ = tr.step(state)
+    fresh = tr.init_state()
+    trained = fresh
+    for _ in range(30):
+        trained, _ = tr.step(trained)
+    return tr, {"fresh": fresh, "trained": trained}
+
+
+# the derived plan forced to each branch, as it picks, and pinned
+PLANS = {
+    "derived": lambda p: p,
+    "derived-dense": lambda p: dataclasses.replace(p, compact_below=0.0),
+    "derived-compacted": lambda p: dataclasses.replace(
+        p, compact_below=math.inf),
+    "pinned-64": lambda p: three_branch.Plan(g=p.g, tile_size=p.tile_size,
+                                             capacity=64),
+    "pinned-777": lambda p: three_branch.Plan(g=p.g, tile_size=p.tile_size,
+                                              capacity=777),
+    "pinned-100000": lambda p: three_branch.Plan(
+        g=p.g, tile_size=p.tile_size, capacity=100_000),
+}
+
+
+@pytest.mark.parametrize("state_name", ["fresh", "trained"])
+@pytest.mark.parametrize("plan_name", list(PLANS))
+def test_compacted_path_equals_reference(states, plan_name, state_name):
+    tr, by_name = states
+    state, cfg = by_name[state_name], tr.config
+    n = tr.n_padded_tokens
+    assert n % tr.plan.capacity != 0
+    plan = PLANS[plan_name](tr.plan)
     key = jax.random.PRNGKey(9)
-    for cap in (64, 777, 100_000):
-        plan_ref = three_branch.Plan(g=2, tile_size=512, capacity=None)
-        plan_cap = three_branch.Plan(g=2, tile_size=512, capacity=cap)
-        t_ref, s_ref = three_branch.sample(
-            key, plan_ref, tr.word_ids, tr.doc_ids, state.topics,
-            state.D, state.W, cfg)
-        t_cap, s_cap = three_branch.sample(
-            key, plan_cap, tr.word_ids, tr.doc_ids, state.topics,
-            state.D, state.W, cfg)
-        assert bool(jnp.all(t_ref == t_cap))
-        assert float(s_ref.frac_skipped) == float(s_cap.frac_skipped)
+    plan_ref = three_branch.Plan(g=plan.g, tile_size=plan.tile_size,
+                                 capacity=None)
+    t_ref, s_ref = three_branch.sample(
+        key, plan_ref, tr.word_ids, tr.doc_ids, state.topics,
+        state.D, state.W, cfg)
+    t_cap, s_cap = three_branch.sample(
+        key, plan, tr.word_ids, tr.doc_ids, state.topics,
+        state.D, state.W, cfg)
+    assert bool(jnp.all(t_ref == t_cap))
+    for field in ("frac_skipped", "frac_m_final", "frac_unchanged",
+                  "frac_at_max"):
+        assert float(getattr(s_ref, field)) == float(getattr(s_cap, field))
+    # the branch that ran: compacted iff its chunk slots fall under the
+    # plan's share of the tokens, always with a pinned capacity
+    skipped = round(float(s_ref.frac_skipped) * n)
+    slots = min(math.ceil((n - skipped) / plan.capacity) * plan.capacity, n)
+    # (at this N the survivor estimate reads every token)
+    compacted = plan.compact_below is None or \
+        slots < plan.compact_below * n
+    assert float(s_cap.phase2_compacted) == float(compacted)
+    assert float(s_cap.frac_phase2_slots) == pytest.approx(
+        slots / n if compacted else 1.0, abs=1e-6)
+    if plan_name == "derived":
+        # the derived plan draws every token of a fresh state and only
+        # the survivors of a trained one
+        assert compacted == (state_name == "trained")
+    assert float(s_ref.phase2_compacted) == 0.0
+
+
+@pytest.mark.parametrize("n,tile", [
+    (25_028_711, 8192), (23_070_469, 8192), (64 * 8192, 8192),
+    (64 * 8192 + 1, 8192), (8192 * 5, 8192), (100, 8192), (2_368, 16)])
+def test_derived_capacity(n, tile):
+    cap = three_branch.derived_capacity(n, tile)
+    assert 1 <= cap <= n
+    n_chunks = -(-n // cap)
+    if cap < n:
+        assert cap % tile == 0
+    if n >= three_branch.TARGET_CHUNKS * tile:
+        # about TARGET_CHUNKS chunks at full survivorship
+        assert three_branch.TARGET_CHUNKS // 2 <= n_chunks \
+            <= three_branch.TARGET_CHUNKS
+    else:
+        assert cap == min(tile, n)
+
+
+def test_build_plan_derives_or_pins(small_corpus):
+    cfg = LDAConfig(n_topics=16, tile_size=16)
+    plan = three_branch.build_plan(small_corpus, cfg)
+    assert plan.capacity == three_branch.derived_capacity(2_368, 16) == 48
+    assert plan.compact_below == three_branch.COMPACT_BELOW
+    pinned = three_branch.build_plan(
+        small_corpus, dataclasses.replace(cfg, survivor_capacity=200))
+    assert (pinned.capacity, pinned.compact_below) == (200, None)
 
 
 def test_llpt_rises_and_skip_grows(small_corpus):

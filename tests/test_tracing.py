@@ -2,8 +2,10 @@
 
   * ``fit``'s ``history["lowered"]`` (the program's compile counter) names
     the programs a call lowered: on the default path (stepwise,
-    three-branch, no survivor capacity) the sampler and the count rebuild.
-  * ``frac_phase2_slots`` counts the exact-draw slots phase 2 computed.
+    three-branch, survivor capacity derived) the sampler and the count
+    rebuild, whichever phase-2 branch the sampler takes.
+  * ``frac_phase2_slots`` counts the exact-draw slots phase 2 computed,
+    ``phase2_compacted`` the branch that computed them.
   * The stepwise loop's spans land in a profiler trace.
 
 Each test builds its engine at a topic count no other test uses, so the
@@ -24,7 +26,7 @@ from repro.runtime import compiles
 
 # the default step's programs, in the order a fresh engine lowers them
 # (the init's count build lowers ``jit_update_counts`` first)
-PHASES = ["jit_update_counts", "jit__sample_reference"]
+PHASES = ["jit_update_counts", "jit__sample_adaptive"]
 
 
 @pytest.fixture(scope="module")
@@ -38,15 +40,30 @@ def _engine(corpus, n_topics, **kw):
                                        eval_every=5, **kw))
 
 
+def _converged_payload(eng):
+    """The engine's payload with every token of a word on one topic: the
+    skip test passes nearly everywhere, so the sampler compacts."""
+    payload = eng.host_payload()
+    words = np.asarray(eng.trainer.corpus.word_ids)
+    return dict(payload, topics_global=(words % eng.config.n_topics)
+                .astype(payload["topics_global"].dtype))
+
+
 def test_fit_lowers_the_named_phase_programs(corpus):
     eng = _engine(corpus, 13)
     log = []
-    lowered = eng.fit(1, log_fn=log.append)["lowered"]
+    first = eng.fit(1, log_fn=log.append)
+    lowered = first["lowered"]
     assert [n for n in lowered if n in PHASES] == PHASES
     assert "jit_token_ll" in lowered                 # the eval, (·, K) shapes
     assert not any("lowered after" in line for line in log)
-    # a second call runs the compiled programs: it lowers nothing
-    assert eng.fit(2, log_fn=log.append)["lowered"] == []
+    # a second call runs the compiled programs: it lowers nothing, though
+    # the sampler switches to its other phase-2 branch as tokens converge
+    second = eng.fit(29, log_fn=log.append)
+    assert second["lowered"] == []
+    assert [s["phase2_compacted"] for s in first["stats"]] == [0.0]
+    branches = [s["phase2_compacted"] for s in second["stats"]]
+    assert branches[0] == 0.0 and branches[-1] == 1.0
     assert not any("lowered after" in line for line in log)
     assert eng.history["lowered"] == lowered
 
@@ -74,8 +91,23 @@ def _expected_slots(stats, n, capacity):
 
 
 def test_phase2_slots_reference_path(corpus):
+    # the default path draws every token of a fresh engine's first
+    # iteration: almost nothing skips yet
     hist = _engine(corpus, 14).fit(2)
     assert [s["frac_phase2_slots"] for s in hist["stats"]] == [1.0]
+    assert [s["phase2_compacted"] for s in hist["stats"]] == [0.0]
+
+
+def test_phase2_slots_default_path_compacts(corpus):
+    eng = _engine(corpus, 16)
+    eng.fit(0)
+    hist = eng.restore(_converged_payload(eng)).fit(1)
+    n, capacity = eng.trainer.n_padded_tokens, eng.trainer.plan.capacity
+    (st,) = hist["stats"]
+    assert st["phase2_compacted"] == 1.0
+    assert st["frac_phase2_slots"] == pytest.approx(
+        _expected_slots(st, n, capacity), abs=1e-6)
+    assert st["frac_phase2_slots"] < 1.0
 
 
 @pytest.mark.parametrize("capacity", [64, 777, 100_000])
