@@ -82,7 +82,6 @@ def main(argv=None) -> int:
     sys.path[:0] = [str(ROOT), str(ROOT / "src")]
     from bench import harness
     harness.enable_compile_cache()
-    from repro.lda.api import LDAEngine
 
     cell = harness.load_cell(args.workload)
     harness.devices(cell.chips, require_tpu=True)
@@ -94,7 +93,7 @@ def main(argv=None) -> int:
         g, corpus = harness.make_corpus(cell, seed)
         payload = harness.warm_payload(g, cell, seed) \
             if cell.traffic["init"] == "planted" else None
-        engine = LDAEngine(corpus, harness.lda_config(cell))
+        engine = harness.make_engine(corpus, cell)
         del corpus
         snap = harness.checked_step(engine, cell.traffic, payload)
         win = harness.measure(engine, clock,

@@ -109,10 +109,49 @@ def devices(chips: int, require_tpu: bool) -> dict:
     if require_tpu and jax.default_backend() != "tpu":
         raise NoChip(f"no TPU: JAX's backend is {jax.default_backend()!r}; "
                      "the benchmark never falls back to another device")
-    if require_tpu and len(devs) < chips:
+    if len(devs) < chips:
         raise NoChip(f"the cell needs {chips} chips, JAX finds {len(devs)}")
     return {"platform": devs[0].platform, "kind": devs[0].device_kind,
             "count": chips}
+
+
+def make_engine(corpus, cell: Cell):
+    """The engine on exactly the cell's chips. Where JAX sees that many
+    devices it is built as a user would build it, with the engine's own
+    choice of backend; where it sees more, on the first ``chips`` of them:
+    the single backend for one, a (``chips``, 1) data mesh for more."""
+    import jax
+    from repro.lda.api import LDAEngine
+    cfg = lda_config(cell)
+    if jax.device_count() == cell.chips:
+        return LDAEngine(corpus, cfg)
+    if cell.chips == 1:
+        return LDAEngine(corpus, cfg, backend="single")
+    from repro.runtime.compat import make_mesh
+    mesh = make_mesh((cell.chips, 1), ("data", "model"),
+                     devices=jax.devices()[:cell.chips])
+    return LDAEngine(corpus, cfg, backend="distributed", mesh=mesh)
+
+
+def check_held_devices(engine, chips: int) -> None:
+    """Log the devices the engine's state lives on; a state outside the
+    cell's first ``chips`` devices would measure another cell."""
+    import jax
+    held = sorted({d.id for leaf in jax.tree.leaves(engine.state)
+                   if isinstance(leaf, jax.Array) for d in leaf.devices()})
+    ours = [d.id for d in jax.devices()[:chips]]
+    log(f"[engine] holds devices {held} of the cell's {ours}")
+    if not set(held) <= set(ours):
+        raise RuntimeError(f"the engine holds devices {held}, outside the "
+                           f"cell's {ours}")
+
+
+def memory_peaks(chips: int) -> list[int]:
+    """``peak_bytes_in_use`` of each of the cell's devices (0 where the
+    backend reports none)."""
+    import jax
+    return [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+            for d in jax.devices()[:chips]]
 
 
 def enable_compile_cache() -> None:
@@ -185,13 +224,66 @@ def check_sample(n: int, seed: int) -> np.ndarray:
 
 
 @dataclasses.dataclass
+class Counts:
+    """The program's counts in document order, whatever its layout."""
+    D: np.ndarray         # (n_docs, K)
+    W: np.ndarray         # (V, K)
+    stray: int = 0        # counts held in rows that are no document's own
+
+
+def document_counts(state, trainer) -> Counts:
+    """D as (n_docs, K) and W as (V, K) from the engine's state.
+
+    The single backend's dense state is taken as it is. A distributed dense
+    state keeps D as (shards, rows, K): shard ``s``'s first
+    ``docs_per_shard[s]`` rows are documents ``doc_map[s]``, the rest pad
+    rows. A count in a pad row, or in a second row of one document, is
+    counted as ``stray`` and shows in ``count_gap``. Any other layout is
+    refused by name rather than read as something it is not."""
+    from repro.lda.distributed import DistLDAState
+    from repro.lda.model import LDAState
+    if isinstance(state, LDAState):
+        return Counts(np.asarray(state.D), np.asarray(state.W))
+    if isinstance(state, DistLDAState) and trainer.sc.owns is None:
+        return sharded_counts(np.asarray(state.D), np.asarray(state.W),
+                              trainer.sc.doc_map, trainer.sc.docs_per_shard,
+                              trainer.corpus.n_docs)
+    layout = type(state).__name__
+    if isinstance(state, DistLDAState):
+        layout += " with documents replicated across shards"
+    raise TypeError(f"no document-order reading of the counts of a {layout}")
+
+
+def sharded_counts(D_sh: np.ndarray, W: np.ndarray, doc_map: np.ndarray,
+                   docs_per_shard: np.ndarray, n_docs: int) -> Counts:
+    """Document-order counts from per-shard D rows (see
+    ``document_counts``); W is replicated and taken as it is."""
+    if D_sh.ndim != 3 or W.ndim != 2 or W.shape[1] != D_sh.shape[2]:
+        raise ValueError(f"sharded D {D_sh.shape} and replicated W "
+                         f"{W.shape}: expected (S, rows, K) and (V, K)")
+    D = np.zeros((n_docs, D_sh.shape[2]), D_sh.dtype)
+    held = np.zeros(n_docs, bool)
+    stray = 0
+    for s, rows in enumerate(D_sh):
+        nd = int(docs_per_shard[s])
+        ids = np.asarray(doc_map[s][:nd], np.int64)
+        stray += int(np.abs(rows[nd:]).sum(dtype=np.int64))
+        own = np.zeros(nd, bool)
+        own[np.unique(ids, return_index=True)[1]] = True
+        own &= ~held[ids]
+        stray += int(np.abs(rows[:nd][~own]).sum(dtype=np.int64))
+        D[ids[own]] = rows[:nd][own]
+        held[ids[own]] = True
+    return Counts(D, W, stray)
+
+
+@dataclasses.dataclass
 class Snapshots:
     """What set-up's checked iteration leaves for the check."""
     topics: list          # z_0, z_1 (host, canonical order)
     key0: np.ndarray      # the key that drew z_1
     iteration: int        # the engine's iteration after it
-    D: np.ndarray         # the program's counts after it
-    W: np.ndarray
+    counts: Counts        # the program's counts after it
     seconds: float = 0.0  # host time spent taking them (not set-up)
 
 
@@ -213,8 +305,7 @@ def checked_step(engine, traffic: dict, payload: dict | None) -> Snapshots:
                              engine.host_payload()["topics_global"]],
                      key0=np.asarray(p0["key"], np.uint32),
                      iteration=int(hist["iteration"][-1]),
-                     D=np.asarray(engine.state.D),
-                     W=np.asarray(engine.state.W))
+                     counts=document_counts(engine.state, engine.trainer))
     snap.seconds = spent + time.perf_counter() - t0
     return snap
 
@@ -227,8 +318,7 @@ class Window:
     compiles: int
     history: dict               # what the window's ``fit`` returned
     topics: np.ndarray = None   # the program's state after it (host)
-    D: np.ndarray = None
-    W: np.ndarray = None
+    counts: Counts = None
 
 
 def measure(engine, clock: CompileClock, n_iters: int,
@@ -250,8 +340,7 @@ def measure(engine, clock: CompileClock, n_iters: int,
 
 def keep_final(engine, win: Window) -> None:
     win.topics = engine.host_payload()["topics_global"]
-    win.D = np.asarray(engine.state.D)
-    win.W = np.asarray(engine.state.W)
+    win.counts = document_counts(engine.state, engine.trainer)
 
 
 @dataclasses.dataclass
@@ -294,6 +383,12 @@ def replay(g, cfg: dict, snap: Snapshots, win: Window,
     return Replay(ref, np.asarray(step), np.asarray(window), llpt, chain)
 
 
+def count_gap(ref, topics, counts: Counts) -> int:
+    """Σ|ΔD| + Σ|ΔW| against the recount, plus the counts the program
+    holds where no document is."""
+    return ref.count_gap(topics, counts.D, counts.W) + counts.stray
+
+
 def compare(rep: Replay, snap: Snapshots, win: Window,
             sample: np.ndarray) -> dict:
     """The numbers the check holds to the cell's limits."""
@@ -304,8 +399,8 @@ def compare(rep: Replay, snap: Snapshots, win: Window,
                                              rep.step),
         "window_mismatch": reference.mismatch(win.topics[sample],
                                               rep.window),
-        "count_gap": max(rep.ref.count_gap(snap.topics[1], snap.D, snap.W),
-                         rep.ref.count_gap(win.topics, win.D, win.W)),
+        "count_gap": max(count_gap(rep.ref, snap.topics[1], snap.counts),
+                         count_gap(rep.ref, win.topics, win.counts)),
         # no LLPT to compare is no sound reading
         "llpt_gap": max((abs(float(reported[it]) - v) / abs(v)
                          for it, v in rep.llpt.items()),
@@ -316,19 +411,17 @@ def compare(rep: Replay, snap: Snapshots, win: Window,
 def run(name: str, seed: int, seconds: float, trace: bool, *,
         t_start: float, require_tpu: bool = True,
         root: pathlib.Path = ROOT, bench: pathlib.Path = BENCH) -> dict:
-    import jax
-    from repro.lda.api import LDAEngine
-
     cell = load_cell(name, root, bench)
     device = devices(cell.chips, require_tpu)
     clock = CompileClock()
     g, corpus = make_corpus(cell, seed)
     payload = warm_payload(g, cell, seed) \
         if cell.traffic["init"] == "planted" else None
-    engine = LDAEngine(corpus, lda_config(cell))
+    engine = make_engine(corpus, cell)
     del corpus
     log(f"[engine] backend={engine.backend_name} config={engine.config}")
     snap = checked_step(engine, cell.traffic, payload)
+    check_held_devices(engine, cell.chips)
     setup_s = time.perf_counter() - t_start - snap.seconds
     log(f"[setup] setup_s={setup_s:.3f} compile_s={clock.seconds:.3f} "
         f"lowerings={clock.lowerings} check_copies_s={snap.seconds:.3f}")
@@ -338,8 +431,9 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
     win = measure(engine, clock, window_iters(cell, seconds),
                   trace_dir if trace else None)
     tokens_per_s = g.n_tokens * win.n_iters / win.wall / cell.chips
-    mem = jax.devices()[0].memory_stats() or {}
-    device["memory_peak_bytes"] = int(mem.get("peak_bytes_in_use", 0))
+    peaks = memory_peaks(cell.chips)
+    device["memory_peak_bytes"] = max(peaks)
+    device["memory_peak_bytes_per_chip"] = peaks
     log(f"[window] iterations={win.n_iters} calls=1 wall_s={win.wall:.4f} "
         f"compiles_in_window={win.compiles} tokens_per_s={tokens_per_s:.1f}")
     keep_final(engine, win)
@@ -382,6 +476,7 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
     checks = {k: {"value": readings[k], "limit": limits[k]} for k in limits}
     correct = all(c["value"] <= c["limit"] for c in checks.values())
     log(f"[check] reference_s={time.perf_counter() - t1:.3f} "
+        f"memory_peak_bytes_after={memory_peaks(1)[0]} "
         f"llpt_reported={win.history['llpt']}")
     result = {"correct": correct, "attempted": win.n_iters, "failed": 0,
               "metrics": metrics, "device": device}

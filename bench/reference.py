@@ -28,6 +28,7 @@ import numpy as np
 
 BLOCK = 8192          # tokens per block: one (BLOCK, K) row gather at a time
 ROUNDING = 1e-5      # share of a token's total mass: rounding room at a boundary
+GAP_ELEMENTS = 1 << 26  # the program's counts on the device at a time (256 MiB)
 
 
 @functools.partial(jax.jit, static_argnames=("n_docs", "n_words", "n_topics"))
@@ -104,6 +105,28 @@ def token_loglik(words, docs, D, W, colsum, *, alpha, beta,
     return _blocks(block, words, docs)
 
 
+@jax.jit
+def _block_gap(ref, start, block):
+    part = jax.lax.dynamic_slice_in_dim(ref, start, block.shape[0])
+    return jnp.sum(jnp.abs(part - block))
+
+
+def abs_gap(ref, prog: np.ndarray, block_rows: int) -> int:
+    """Σ|ref - prog| for a device array ``ref`` and a host array ``prog``
+    of its shape, sent to the device ``block_rows`` rows at a time, so the
+    device holds ``ref`` and one block of ``prog``."""
+    prog = np.asarray(prog)
+    if prog.shape != ref.shape:
+        raise ValueError(f"the program's counts have shape {prog.shape}, "
+                         f"the recount {ref.shape}")
+    total = 0
+    for start in range(0, prog.shape[0], block_rows):
+        block = jnp.asarray(prog[start:start + block_rows])
+        total += int(_block_gap(ref, start, block))
+        del block
+    return total
+
+
 def step_keys(key_data: np.ndarray):
     """(next key data, uniform key) of one iteration from a key's data."""
     nxt, sub = jax.random.split(jnp.asarray(key_data, jnp.uint32))
@@ -150,8 +173,8 @@ class Reference:
     def count_gap(self, topics, D_prog, W_prog) -> int:
         """Σ|ΔD| + Σ|ΔW| between the program's counts and the recount."""
         D, W, _ = self.counts(topics)
-        return int(jnp.sum(jnp.abs(D - jnp.asarray(D_prog)))
-                   + jnp.sum(jnp.abs(W - jnp.asarray(W_prog))))
+        rows = max(1, GAP_ELEMENTS // self.shape["n_topics"])
+        return abs_gap(D, D_prog, rows) + abs_gap(W, W_prog, rows)
 
 
 def mismatch(program_topics, draws) -> float:

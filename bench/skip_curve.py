@@ -37,7 +37,6 @@ def main(argv=None) -> int:
     sys.path[:0] = [str(ROOT), str(ROOT / "src")]
     from bench import harness
     harness.enable_compile_cache()
-    from repro.lda.api import LDAEngine
 
     cell = harness.load_cell(args.workload)
     harness.devices(cell.chips, require_tpu=True)
@@ -52,7 +51,7 @@ def main(argv=None) -> int:
                   flush=True)
 
     curve: list = []
-    engine = LDAEngine(corpus, harness.lda_config(cell))
+    engine = harness.make_engine(corpus, cell)
     t0 = time.perf_counter()
     while time.perf_counter() - t0 < 60.0 * args.minutes:
         emit("cold", engine.fit(10), t0)
@@ -64,7 +63,7 @@ def main(argv=None) -> int:
     gc.collect()
 
     curve = []
-    engine = LDAEngine(corpus, harness.lda_config(cell))
+    engine = harness.make_engine(corpus, cell)
     engine.restore(harness.warm_payload(g, cell, args.seed))
     emit("planted", engine.fit(2), time.perf_counter())
     return 0

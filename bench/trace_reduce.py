@@ -16,6 +16,11 @@ import re
 DEVICE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
+# HLO collective opcodes, async ``-start``/``-done`` forms included
+COLLECTIVE = re.compile(r"(?:all-reduce|all-gather|reduce-scatter|"
+                        r"collective-permute|all-to-all)(?:-start|-done)?")
+# the opcode in an op's HLO text: ``%psum.48 = s32[..]{..} all-reduce(..``
+OPCODE = re.compile(r" = .*?(?:^|\s)([a-z][a-z0-9-]*)\(")
 
 
 @dataclasses.dataclass
@@ -110,6 +115,26 @@ def module_time(trace: Trace, dev: str, lo: float, hi: float) -> dict:
             * 1e-9 for name, ivs in by_name.items()}
 
 
+def is_collective(op_name: str) -> bool:
+    """Whether a trace op is a collective, by its HLO opcode: a TPU trace
+    names each op by its HLO text, whose instruction name is JAX's (the
+    W-delta all-reduce is ``%psum.48 = ... all-reduce(...)``); a bare name
+    (``all-reduce.3``) is its opcode and a number."""
+    m = OPCODE.search(op_name)
+    opcode = m.group(1) if m else re.sub(r"\.\d+$", "", op_name.lstrip("%"))
+    return COLLECTIVE.fullmatch(opcode) is not None
+
+
+def collective_time(trace: Trace, dev: str, lo: float, hi: float) -> float:
+    """Device seconds inside collective operations: the busy time that
+    falls within the union of their intervals, so a collective nested in
+    another operation's interval (a ``while``'s) counts once."""
+    before = busy_before(busy_intervals(trace, dev, lo, hi))
+    ivs = [(s, e) for name, s, e in trace.ops.get(dev, [])
+           if is_collective(name)]
+    return sum(before(e) - before(s) for s, e in union(ivs, lo, hi)) * 1e-9
+
+
 def busy_before(merged):
     """``t -> busy length before t`` for merged intervals."""
     starts = [s for s, _ in merged]
@@ -141,8 +166,8 @@ def label_gaps(trace: Trace, idle, n: int = 10, exclude=()):
 
 def reduce(trace: Trace, window_span: str = "bench.window",
            n_chips: int = 1) -> dict:
-    """busy and window seconds, per-module device seconds and the
-    breakdown, over the first ``n_chips`` devices of the trace. The
+    """busy and window seconds, per-module and collective device seconds
+    and the breakdown, over the first ``n_chips`` devices of the trace. The
     breakdown's device operations are the programs (XLA modules) that
     took most device time: they partition it, where the trace's
     operations nest (a ``while`` holds its body's operations)."""
@@ -163,6 +188,8 @@ def reduce(trace: Trace, window_span: str = "bench.window",
         "busy_s": busy_s,
         "window_s": (hi - lo) * 1e-9,
         "module_s": modules,
+        "collective_s": sum(collective_time(trace, d, lo, hi)
+                            for d in devs) / len(devs),
         "breakdown": {
             "device_ops": [[name, s] for name, s in top],
             "idle_gaps": label_gaps(trace, gaps(busy[d0], lo, hi),
