@@ -53,8 +53,9 @@ __all__ = [
     "WordStats", "word_stats", "SkipDecision", "skip_phase",
     "exact_three_branch", "exact_three_branch_tiled", "ThreeBranchStats",
     "chunk_slots", "sample", "derived_capacity",
-    "build_plan", "Plan", "survivor_rank", "compact_survivor_indices",
-    "map_token_tiles", "run_survivor_chunks",
+    "build_plan", "plan_for", "Plan", "survivor_rank",
+    "compact_survivor_indices", "map_token_tiles", "run_survivor_chunks",
+    "sample_from_uniforms", "uniforms_at",
 ]
 
 
@@ -71,14 +72,22 @@ class WordStats(NamedTuple):
     wsum: jax.Array       # (V,)  ΣŴ[v]
 
 
-@functools.partial(jax.jit, static_argnames=("g", "alpha"))
-def word_stats(W_hat: jax.Array, *, g: int, alpha: float) -> WordStats:
+@functools.partial(jax.jit, static_argnames=("g", "alpha", "pair_barrier"))
+def word_stats(W_hat: jax.Array, *, g: int, alpha: float,
+               pair_barrier: bool = True) -> WordStats:
     # The barrier stops XLA:CPU from fusing the top-k sort into each
     # consumer (which re-runs the sort per use — measured 30× slower).
-    # Identity on values, so results are bit-identical.
+    # Identity on values, so results are bit-identical. Inside a
+    # shard_map XLA:CPU aborts on one barrier over top_k's pair, so a
+    # data shard (``pair_barrier=False``) puts one on each output; a TPU
+    # drops either form, but they compile to different one-chip programs.
     n_top = min(g + 1, W_hat.shape[-1])
-    vals, idxs = jax.lax.optimization_barrier(
-        jax.lax.top_k(W_hat, n_top))                        # (V, n_top)
+    if pair_barrier:
+        vals, idxs = jax.lax.optimization_barrier(
+            jax.lax.top_k(W_hat, n_top))                    # (V, n_top)
+    else:
+        vals, idxs = map(jax.lax.optimization_barrier,
+                         jax.lax.top_k(W_hat, n_top))
     if n_top < g + 1:
         # K ≤ g: no mass lies past the K real topics, so the missing
         # a_i are 0 and Eq 10's tail term vanishes (S_est is then exact)
@@ -268,14 +277,84 @@ def build_plan(corpus, config) -> Plan:
     """An explicit ``survivor_capacity`` pins the chunk capacity and always
     compacts; unset, the capacity is derived from the padded corpus and
     the sampler picks its phase 2 from each iteration's survivors."""
+    tile = config.tile_size
+    return plan_for(-(-corpus.n_tokens // tile) * tile, config)
+
+
+def plan_for(n_tokens: int, config) -> Plan:
+    """``build_plan`` for a sampler call over ``n_tokens`` token slots (the
+    trainer's padded corpus, or one data shard's tokens)."""
     if config.survivor_capacity:
         return Plan(g=config.g, tile_size=config.tile_size,
                     capacity=int(config.survivor_capacity))
-    tile = config.tile_size
-    n = -(-corpus.n_tokens // tile) * tile      # the trainer's padded count
-    return Plan(g=config.g, tile_size=tile,
-                capacity=derived_capacity(n, tile),
+    return Plan(g=config.g, tile_size=config.tile_size,
+                capacity=derived_capacity(n_tokens, config.tile_size),
                 compact_below=COMPACT_BELOW)
+
+
+def _key_uniforms(key, n):
+    """The iteration's uniforms from its key: ``uniform(key, (n,))``."""
+    return jax.random.uniform(key, (n,), dtype=jnp.float32)
+
+
+def uniforms_at(key, positions):
+    """``uniform(key, (N,))[positions]`` for any ``N`` past the largest
+    position, without drawing the other values.
+
+    JAX's partitionable threefry (its default) gives element ``i`` of a
+    1-D draw the counter ``i``: the bits are ``threefry2x32(key, (0, i))``
+    folded by xor, and the float is made from them as ``jax.random.uniform``
+    makes it (the top 23 bits as the mantissa of a number in [1, 2),
+    less 1). So a data shard draws its tokens' values of the single
+    backend's one vector from their global positions.
+    """
+    if not jax.config.jax_threefry_partitionable:
+        raise ValueError(
+            "uniforms_at needs jax_threefry_partitionable (JAX's default): "
+            "without it an element of a draw depends on the draw's length")
+    from jax.extend.random import threefry2x32_p
+    k = jax.random.key_data(key)
+    lo = positions.astype(jnp.uint32)
+    b1, b2 = threefry2x32_p.bind(k[0], k[1], jnp.zeros_like(lo), lo)
+    mant = jax.lax.shift_right_logical(b1 ^ b2, jnp.uint32(32 - 23))
+    one = jnp.uint32(0x3F800000)                     # 1.0f's bits
+    return jax.lax.bitcast_convert_type(mant | one, jnp.float32) \
+        - jnp.float32(1.0)
+
+
+def _given_uniforms(u, n):
+    """The iteration's uniforms, drawn by the caller (a data shard's
+    tokens' values of the one ``uniform(key, (N,))``)."""
+    return u
+
+
+def _reference_body(src, word_ids, doc_ids, old_topics, D, W_hat, *, g,
+                    alpha, tile_size, uniforms=_key_uniforms,
+                    token_mean=jnp.mean, stats=word_stats):
+    """``_sample_reference``'s work. ``uniforms(src, n)`` gives the
+    tokens' uniforms where the draw has always been made, ``token_mean``
+    reduces each per-token stat and ``stats`` makes the word stats, so the
+    one-chip program keeps its exact form while a data shard passes its
+    own uniforms, a masked reduction and shard_map-safe word stats."""
+    stats_w = stats(W_hat, g=g, alpha=alpha)
+    n = word_ids.shape[0]
+    u = uniforms(src, n)
+    dec = skip_phase(u, word_ids, doc_ids, D, stats_w, g=g, alpha=alpha)
+    topics_exact, in_m = exact_three_branch(
+        u, word_ids, doc_ids, stats_w.k[:, 0], D, W_hat,
+        alpha=alpha, tile_size=tile_size)
+    # Skip ⇒ K1; theorem guarantees topics_exact == K1 there (tested).
+    new_topics = jnp.where(dec.skip, dec.k1, topics_exact)
+    st = ThreeBranchStats(
+        frac_skipped=token_mean(dec.skip.astype(jnp.float32)),
+        frac_m_final=token_mean(in_m.astype(jnp.float32)),
+        frac_unchanged=token_mean(
+            (new_topics == old_topics).astype(jnp.float32)),
+        frac_at_max=token_mean((new_topics == dec.k1).astype(jnp.float32)),
+        frac_phase2_slots=jnp.float32(1.0),     # every token is drawn
+        phase2_compacted=jnp.float32(0.0),
+    )
+    return new_topics, st
 
 
 @functools.partial(jax.jit, static_argnames=("g", "alpha", "tile_size"))
@@ -290,24 +369,8 @@ def _sample_reference(key, word_ids, doc_ids, old_topics, D, W_hat,
     nested programs' names in the op metadata (``jit(word_stats)``,
     ``jit(skip_phase)``, ``jit(exact_three_branch)``).
     """
-    stats_w = word_stats(W_hat, g=g, alpha=alpha)
-    n = word_ids.shape[0]
-    u = jax.random.uniform(key, (n,), dtype=jnp.float32)
-    dec = skip_phase(u, word_ids, doc_ids, D, stats_w, g=g, alpha=alpha)
-    topics_exact, in_m = exact_three_branch(
-        u, word_ids, doc_ids, stats_w.k[:, 0], D, W_hat,
-        alpha=alpha, tile_size=tile_size)
-    # Skip ⇒ K1; theorem guarantees topics_exact == K1 there (tested).
-    new_topics = jnp.where(dec.skip, dec.k1, topics_exact)
-    st = ThreeBranchStats(
-        frac_skipped=jnp.mean(dec.skip.astype(jnp.float32)),
-        frac_m_final=jnp.mean(in_m.astype(jnp.float32)),
-        frac_unchanged=jnp.mean((new_topics == old_topics).astype(jnp.float32)),
-        frac_at_max=jnp.mean((new_topics == dec.k1).astype(jnp.float32)),
-        frac_phase2_slots=jnp.float32(1.0),     # every token is drawn
-        phase2_compacted=jnp.float32(0.0),
-    )
-    return new_topics, st
+    return _reference_body(key, word_ids, doc_ids, old_topics, D, W_hat,
+                           g=g, alpha=alpha, tile_size=tile_size)
 
 
 def compact_survivor_indices(rank, skip, total_slots):
@@ -384,22 +447,13 @@ def run_survivor_chunks(surv_idx, n_surv, init_topics, *, capacity,
                              (init_topics, jnp.zeros(n, jnp.bool_)))
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("g", "alpha", "capacity", "tile_size"))
-def _sample_compacted(key, word_ids, doc_ids, old_topics, D, W_hat,
-                      *, g, alpha, capacity, tile_size):
-    """Compacted path as ONE dispatch: fori_loop over a static chunk budget.
-
-    The chunk budget is ceil(N/capacity) — full coverage, so correctness
-    never depends on how many tokens actually survive — but each chunk body
-    is guarded by ``lax.cond(lo < n_surv, ...)``: chunks past the survivor
-    tail cost one predicate, not one kernel. The survivor count therefore
-    never leaves the device (the seed's ``int(n_surv)`` sync is gone) and
-    runtime phase-2 work stays proportional to ceil(survivors/capacity).
-    """
-    stats_w = word_stats(W_hat, g=g, alpha=alpha)
+def _compacted_body(src, word_ids, doc_ids, old_topics, D, W_hat, *, g,
+                    alpha, capacity, tile_size, uniforms=_key_uniforms,
+                    token_mean=jnp.mean, stats=word_stats):
+    """``_sample_compacted``'s work; the hooks as in ``_reference_body``."""
+    stats_w = stats(W_hat, g=g, alpha=alpha)
     n = word_ids.shape[0]
-    u = jax.random.uniform(key, (n,), dtype=jnp.float32)
+    u = uniforms(src, n)
     dec = skip_phase(u, word_ids, doc_ids, D, stats_w, g=g, alpha=alpha)
     rank, n_surv = survivor_rank(dec.skip)
     k1_per_word = stats_w.k[:, 0]
@@ -415,15 +469,53 @@ def _sample_compacted(key, word_ids, doc_ids, old_topics, D, W_hat,
         surv_idx, n_surv, dec.k1,                           # skipped ⇒ K1
         capacity=capacity, n_chunks=n_chunks, sample_chunk=sample_chunk)
     st = ThreeBranchStats(
-        frac_skipped=jnp.mean(dec.skip.astype(jnp.float32)),
-        frac_m_final=jnp.mean((dec.skip | in_m_acc).astype(jnp.float32)),
-        frac_unchanged=jnp.mean((new_topics == old_topics).astype(jnp.float32)),
-        frac_at_max=jnp.mean((new_topics == dec.k1).astype(jnp.float32)),
+        frac_skipped=token_mean(dec.skip.astype(jnp.float32)),
+        frac_m_final=token_mean((dec.skip | in_m_acc).astype(jnp.float32)),
+        frac_unchanged=token_mean(
+            (new_topics == old_topics).astype(jnp.float32)),
+        frac_at_max=token_mean((new_topics == dec.k1).astype(jnp.float32)),
         frac_phase2_slots=chunk_slots(n_surv, capacity, n).astype(
             jnp.float32) / max(n, 1),
         phase2_compacted=jnp.float32(1.0),
     )
     return new_topics, st
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("g", "alpha", "capacity", "tile_size"))
+def _sample_compacted(key, word_ids, doc_ids, old_topics, D, W_hat,
+                      *, g, alpha, capacity, tile_size):
+    """Compacted path as ONE dispatch: fori_loop over a static chunk budget.
+
+    The chunk budget is ceil(N/capacity) — full coverage, so correctness
+    never depends on how many tokens actually survive — but each chunk body
+    is guarded by ``lax.cond(lo < n_surv, ...)``: chunks past the survivor
+    tail cost one predicate, not one kernel. The survivor count therefore
+    never leaves the device (the seed's ``int(n_surv)`` sync is gone) and
+    runtime phase-2 work stays proportional to ceil(survivors/capacity).
+    """
+    return _compacted_body(key, word_ids, doc_ids, old_topics, D, W_hat,
+                           g=g, alpha=alpha, capacity=capacity,
+                           tile_size=tile_size)
+
+
+def _adaptive_body(src, word_ids, doc_ids, old_topics, D, W_hat, *, g,
+                   alpha, capacity, tile_size, compact_below, uniforms,
+                   compacted, reference, stats=word_stats):
+    """``_sample_adaptive``'s work: the estimate, then ``lax.cond``
+    between ``compacted`` and ``reference``, each given ``src``."""
+    n = word_ids.shape[0]
+    stride = max(1, n // ESTIMATE_TOKENS)
+    stats_w = stats(W_hat, g=g, alpha=alpha)
+    u = uniforms(src, n)
+    dec = skip_phase(u[::stride], word_ids[::stride], doc_ids[::stride], D,
+                     stats_w, g=g, alpha=alpha)
+    est_surv = jnp.round(jnp.mean((~dec.skip).astype(jnp.float32)) * n)
+    est_slots = chunk_slots(est_surv.astype(jnp.int32), capacity, n)
+    compact = est_slots.astype(jnp.float32) < jnp.float32(compact_below * n)
+    args = (src, word_ids, doc_ids, old_topics, D, W_hat)
+    return jax.lax.cond(compact, lambda a: compacted(*a),
+                        lambda a: reference(*a), args)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -445,23 +537,14 @@ def _sample_adaptive(key, word_ids, doc_ids, old_topics, D, W_hat,
     arithmetic, so the topics equal ``_sample_reference``'s bit for bit,
     whichever branch runs.
     """
-    n = word_ids.shape[0]
-    stride = max(1, n // ESTIMATE_TOKENS)
-    stats_w = word_stats(W_hat, g=g, alpha=alpha)
-    u = jax.random.uniform(key, (n,), dtype=jnp.float32)
-    dec = skip_phase(u[::stride], word_ids[::stride], doc_ids[::stride], D,
-                     stats_w, g=g, alpha=alpha)
-    est_surv = jnp.round(jnp.mean((~dec.skip).astype(jnp.float32)) * n)
-    est_slots = chunk_slots(est_surv.astype(jnp.int32), capacity, n)
-    compact = est_slots.astype(jnp.float32) < jnp.float32(compact_below * n)
-    args = (key, word_ids, doc_ids, old_topics, D, W_hat)
-    return jax.lax.cond(
-        compact,
-        lambda a: _sample_compacted(*a, g=g, alpha=alpha, capacity=capacity,
-                                    tile_size=tile_size),
-        lambda a: _sample_reference(*a, g=g, alpha=alpha,
-                                    tile_size=tile_size),
-        args)
+    return _adaptive_body(
+        key, word_ids, doc_ids, old_topics, D, W_hat, g=g, alpha=alpha,
+        capacity=capacity, tile_size=tile_size, compact_below=compact_below,
+        uniforms=_key_uniforms,
+        compacted=functools.partial(_sample_compacted, g=g, alpha=alpha,
+                                    capacity=capacity, tile_size=tile_size),
+        reference=functools.partial(_sample_reference, g=g, alpha=alpha,
+                                    tile_size=tile_size))
 
 
 def sample(key, plan: Plan, word_ids, doc_ids, old_topics, D, W, config):
@@ -489,3 +572,31 @@ def sample(key, plan: Plan, word_ids, doc_ids, old_topics, D, W, config):
                             g=plan.g, alpha=alpha, capacity=plan.capacity,
                             tile_size=plan.tile_size,
                             compact_below=plan.compact_below)
+
+
+def sample_from_uniforms(u, plan: Plan, word_ids, doc_ids, old_topics, D,
+                         W_hat, *, alpha: float, token_mean=jnp.mean):
+    """``sample``'s draw with the tokens' uniforms ``u`` given, for a data
+    shard that holds some of the corpus's tokens: with their values of the
+    one ``uniform(key, (N,))``, the same D rows and the same Ŵ, each token
+    draws the topic the single backend draws. Traced inline (call it
+    inside the caller's program); ``token_mean`` reduces each per-token
+    stat, so a shard can leave its pad slots out and sum over the mesh
+    after the branch (no collective may run inside a branch that shards
+    choose apart)."""
+    stats = functools.partial(word_stats, pair_barrier=False)
+    kw = dict(g=plan.g, alpha=alpha, tile_size=plan.tile_size,
+              uniforms=_given_uniforms, token_mean=token_mean, stats=stats)
+    args = (u, word_ids, doc_ids, old_topics, D, W_hat)
+    reference = functools.partial(_reference_body, **kw)
+    if plan.capacity is None:
+        return reference(*args)
+    compacted = functools.partial(_compacted_body, capacity=plan.capacity,
+                                  **kw)
+    if plan.compact_below is None:
+        return compacted(*args)
+    return _adaptive_body(*args, g=plan.g, alpha=alpha,
+                          capacity=plan.capacity, tile_size=plan.tile_size,
+                          compact_below=plan.compact_below,
+                          uniforms=_given_uniforms, compacted=compacted,
+                          reference=reference, stats=stats)

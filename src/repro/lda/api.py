@@ -65,7 +65,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import CheckpointManager
-from repro.core import llpt as llpt_mod, three_branch
+from repro.core import three_branch
 from repro.lda.corpus import Corpus, from_documents, relabel_by_frequency
 from repro.lda.model import LDAConfig
 from repro.lda.trainer import run_boundary_chunked
@@ -665,15 +665,7 @@ class _DistBackend:
         return self.trainer.host_payload(state)
 
     def evaluate(self, state) -> float:
-        D, W = self.trainer.gather_global(state)
-        c = self.corpus
-        return float(llpt_mod.llpt(
-            jnp.asarray(c.word_ids), jnp.asarray(c.doc_ids),
-            jnp.ones(c.n_tokens, jnp.int32),
-            jnp.asarray(D.astype(np.int32)),
-            jnp.asarray(W.astype(np.int32)),
-            alpha=self.config.alpha_, beta=self.config.beta,
-            tile_size=self.config.tile_size))
+        return self.trainer.llpt(state)
 
     def run(self, n_iters: int, state, log_fn, checkpoint_every,
             on_chunk=None):

@@ -55,7 +55,8 @@ from jax import shard_map as _shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import balance as balance_mod
-from repro.core import sparse, three_branch
+from repro.core import esca, sparse, three_branch
+from repro.core import llpt as llpt_mod
 from repro.lda import invariants
 from repro.lda.corpus import Corpus, chunk_documents
 from repro.lda.model import HybridLayout, LDAConfig
@@ -514,6 +515,82 @@ def _dist_step(word_ids, doc_ids, mask, state, *,
     return new_state, stats
 
 
+def _dp_step(word_ids, doc_ids, mask, gpos, state, *, cfg: LDAConfig,
+             data_axes: tuple[str, ...], plan: three_branch.Plan):
+    """One iteration of a data shard of the resident dense data-parallel
+    path (model axis 1, document chunking): the single backend's draw.
+
+    The key advances as ``LDATrainer.step``'s does (``key, sub =
+    split(key)``), and the shard's tokens take their values of the one
+    ``uniform(sub, (N,))`` from their global positions ``gpos`` (scope
+    ``lda.uniforms``). The single backend's sampler then draws them
+    (``three_branch.sample_from_uniforms``, survivor compaction
+    included): each document is whole on one shard, so its D row is the
+    global row, and W is the global W, so every token draws the topic the
+    single backend draws. Pad slots draw too and count nowhere. The
+    counts move by ±1 deltas, W's through one all-reduce (``lda.w_psum``).
+    The stats are means over the real tokens of all shards, summed over
+    the mesh after the sampler's branch; the phase-2 slot share and the
+    branch taken are means over the shards.
+    """
+    word_ids, doc_ids, mask, gpos = word_ids[0], doc_ids[0], mask[0], gpos[0]
+    topics, D, W = state.topics[0], state.D[0], state.W
+    key, sub = jax.random.split(state.key)
+    with jax.named_scope("lda.uniforms"):
+        u = three_branch.uniforms_at(sub, gpos)
+    fmask = mask.astype(jnp.float32)
+    new_topics, st = three_branch.sample_from_uniforms(
+        u, plan, word_ids, doc_ids, topics, D,
+        esca.compute_w_hat(W, cfg.beta), alpha=cfg.alpha_,
+        token_mean=lambda v: jnp.sum(v * fmask))
+    wgt = mask.astype(jnp.int32)
+    with jax.named_scope("lda.count_update"):
+        dW_local = jnp.zeros_like(W).at[word_ids, topics].add(-wgt) \
+                                    .at[word_ids, new_topics].add(wgt)
+        D_new = D.at[doc_ids, topics].add(-wgt).at[doc_ids, new_topics] \
+                 .add(wgt)
+    with jax.named_scope("lda.w_psum"):
+        dW = jax.lax.psum(dW_local, data_axes)            # delta all-reduce
+    denom = jax.lax.psum(jnp.sum(fmask), data_axes)
+
+    def tokens(v):
+        return jax.lax.psum(v, data_axes) / denom
+
+    stats = three_branch.ThreeBranchStats(
+        frac_skipped=tokens(st.frac_skipped),
+        frac_m_final=tokens(st.frac_m_final),
+        frac_unchanged=tokens(st.frac_unchanged),
+        frac_at_max=tokens(st.frac_at_max),
+        frac_q_branch=jnp.float32(0.0),   # not attributed, as on one chip
+        frac_phase2_slots=jax.lax.pmean(st.frac_phase2_slots, data_axes),
+        phase2_compacted=jax.lax.pmean(st.phase2_compacted, data_axes))
+    return DistLDAState(topics=new_topics[None], D=D_new[None], W=W + dW,
+                        key=key, iteration=state.iteration + 1), stats
+
+
+def _shard_llpt(word_ids, doc_ids, mask, D, W, *, cfg: LDAConfig,
+                data_axes: tuple[str, ...]):
+    """LLPT of a dense state whose shards hold whole D rows and the whole
+    W: each shard scores its own tokens (``core/llpt.py``'s per-token
+    terms), and the masked sums meet in one psum."""
+    ll = llpt_mod.token_ll(
+        word_ids[0], doc_ids[0], D[0], W,
+        jnp.sum(W, axis=0, dtype=jnp.float32), alpha=cfg.alpha_,
+        beta=cfg.beta, n_words=W.shape[0], tile_size=cfg.tile_size)
+    m = mask[0].astype(jnp.float32)
+    return jax.lax.psum(jnp.sum(ll * m), data_axes) \
+        / jax.lax.psum(jnp.sum(m), data_axes)
+
+
+def _host_llpt(corpus: Corpus, cfg: LDAConfig, D, W) -> float:
+    """LLPT of global host counts on the default device."""
+    return float(llpt_mod.llpt(
+        jnp.asarray(corpus.word_ids), jnp.asarray(corpus.doc_ids),
+        jnp.ones(corpus.n_tokens, jnp.int32),
+        jnp.asarray(D.astype(np.int32)), jnp.asarray(W.astype(np.int32)),
+        alpha=cfg.alpha_, beta=cfg.beta, tile_size=cfg.tile_size))
+
+
 # ---------------------------------------------------------------------------
 # streamed residency (corpus_residency="streamed", DESIGN.md SS10)
 # ---------------------------------------------------------------------------
@@ -853,13 +930,16 @@ def _host_counts(sc: ShardedCorpus, corpus: Corpus, n_topics: int,
     """(D, W) host count matrices from per-shard topics (see
     DistLDATrainer._build_counts for the replication semantics)."""
     S, K = sc.n_shards, n_topics
-    Dg = np.zeros((corpus.n_docs, K), np.int64)
-    W = np.zeros((corpus.n_words, K), np.int32)
-    for s in range(S):
-        sel = sc.mask[s] > 0
-        gdoc = sc.doc_map[s][sc.doc_ids[s][sel]]
-        np.add.at(Dg, (gdoc, t_np[s][sel]), 1)
-        np.add.at(W, (sc.word_ids[s][sel], t_np[s][sel]), 1)
+    sel = sc.mask > 0
+    gdoc = np.take_along_axis(sc.doc_map, sc.doc_ids.astype(np.int64),
+                              axis=1)[sel]
+    t = np.asarray(t_np)[sel].astype(np.int64)
+    # one histogram pass each (np.add.at takes ~10x as long at 46 M tokens)
+    Dg = np.bincount(gdoc * K + t, minlength=corpus.n_docs * K) \
+        .reshape(corpus.n_docs, K)
+    W = np.bincount(sc.word_ids[sel].astype(np.int64) * K + t,
+                    minlength=corpus.n_words * K) \
+        .reshape(corpus.n_words, K).astype(np.int32)
     D = np.zeros((S, sc.m_local, K), np.int32)
     for s in range(S):
         nd = int(sc.docs_per_shard[s])
@@ -951,11 +1031,24 @@ class DistLDATrainer(_StreamedDistMixin):
                 overflow=P(), key=P(), iteration=P())
         stats_spec = three_branch.ThreeBranchStats(
             *[P()] * len(three_branch.ThreeBranchStats._fields))
+        # the resident dense data-parallel path draws the single backend's
+        # topics (_dp_step); topic blocks, dissected documents and packed
+        # D rows keep the per-shard sweep and its own draw (_dist_step)
+        self.draws_as_single = (self.layout is None and self.pm == 1
+                                and self.sc.shared_slot is None)
         step = functools.partial(
             _dist_step, cfg=config, data_axes=daxes, model_axis="model",
             n_words=corpus.n_words, m_local=self.sc.m_local, g=config.g,
             layout=self.layout)
-        if self.sc.shared_slot is not None:
+        if self.draws_as_single:
+            self.plan = three_branch.plan_for(
+                int(self.sc.word_ids.shape[1]), config)
+            self._sm_step = _shard_map(
+                functools.partial(_dp_step, cfg=config, data_axes=daxes,
+                                  plan=self.plan),
+                mesh=mesh, in_specs=(tok_spec,) * 4 + (self.state_specs,),
+                out_specs=(self.state_specs, stats_spec), check_vma=False)
+        elif self.sc.shared_slot is not None:
             def step_shared(word_ids, doc_ids, mask, shared_slot,
                             shared_rows, state):
                 return step(word_ids, doc_ids, mask, state,
@@ -973,7 +1066,15 @@ class DistLDATrainer(_StreamedDistMixin):
                 out_specs=(self.state_specs, stats_spec),
                 check_vma=False)
         self._step = jax.jit(self._sm_step)
-        self._scan_cache: dict[int, Any] = {}
+        self._llpt = None
+        if self.layout is None and self.pm == 1:
+            # every shard holds whole D rows (a dissected document's row
+            # in full on each holder) and the whole W
+            self._llpt = jax.jit(_shard_map(
+                functools.partial(_shard_llpt, cfg=config, data_axes=daxes),
+                mesh=mesh,
+                in_specs=(tok_spec,) * 3 + (P(daxes, None, None), P()),
+                out_specs=P(), check_vma=False))
 
         from repro.train.lda_step import resolve_residency
         self.residency, self.n_stream_shards = resolve_residency(
@@ -981,7 +1082,9 @@ class DistLDATrainer(_StreamedDistMixin):
         dev = NamedSharding(mesh, tok_spec)
         if self.residency == "streamed":
             # out-of-core: token arrays stay HOST-side; each device
-            # streams its own sub-shard sequence (DESIGN.md SS10)
+            # streams its own sub-shard sequence (DESIGN.md SS10) with
+            # the per-shard sweep and its own draw
+            self.draws_as_single = False
             self._build_stream()
             self._step_inputs = None
             if self.sc.shared_rows is not None:
@@ -992,7 +1095,13 @@ class DistLDATrainer(_StreamedDistMixin):
         self.word_ids = jax.device_put(jnp.asarray(self.sc.word_ids), dev)
         self.doc_ids = jax.device_put(jnp.asarray(self.sc.doc_ids), dev)
         self.mask = jax.device_put(jnp.asarray(self.sc.mask), dev)
-        if self.sc.shared_slot is not None:
+        if self.draws_as_single:
+            # each slot's global token position: its uniform's counter
+            self.global_pos = jax.device_put(
+                self.sc.global_pos.astype(np.uint32), dev)
+            self._step_inputs = (self.word_ids, self.doc_ids, self.mask,
+                                 self.global_pos)
+        elif self.sc.shared_slot is not None:
             self.shared_slot = jax.device_put(
                 jnp.asarray(self.sc.shared_slot), dev)
             self.shared_rows = jax.device_put(
@@ -1002,6 +1111,9 @@ class DistLDATrainer(_StreamedDistMixin):
                                  self.shared_slot, self.shared_rows)
         else:
             self._step_inputs = (self.word_ids, self.doc_ids, self.mask)
+        # run_fused's step: the same program, the state's buffers donated
+        self._run_step = jax.jit(self._sm_step,
+                                 donate_argnums=(len(self._step_inputs),))
 
     def _put(self, x, spec):
         return jax.device_put(jnp.asarray(x), NamedSharding(self.mesh, spec))
@@ -1025,9 +1137,16 @@ class DistLDATrainer(_StreamedDistMixin):
                 put(jnp.int32(0), P()))
 
     def _device_state(self, topics, D, W, key, iteration):
-        """Place (dense host counts, topics) as the configured state format."""
+        """Place (dense host counts, topics) as the configured state format.
+
+        The key and the iteration go to the mesh as the step returns them,
+        so the first call of the step compiles the program every later
+        call runs."""
         counts = self._device_counts(D, W)
         topics = self._put(topics, P(self.data_axes))
+        rep = NamedSharding(self.mesh, P())
+        key = jax.device_put(key, rep)
+        iteration = jax.device_put(jnp.asarray(iteration, jnp.int32), rep)
         if self.layout is None:
             return DistLDAState(topics=topics, D=counts[0], W=counts[1],
                                 key=key, iteration=iteration)
@@ -1050,6 +1169,17 @@ class DistLDATrainer(_StreamedDistMixin):
     def init_state(self):
         cfg = self.cfg
         key = jax.random.PRNGKey(cfg.seed)
+        if self.draws_as_single:
+            # the single backend's initial draw (one split, one randint
+            # in global token order), so one seed trains one chain on
+            # either backend
+            key, sub = jax.random.split(key)
+            return self.state_from_payload({
+                "topics_global": np.asarray(jax.random.randint(
+                    sub, (self.corpus.n_tokens,), 0, cfg.n_topics,
+                    dtype=jnp.int32)),
+                "key": np.asarray(jax.random.key_data(key)),
+                "iteration": 0})
         # the SAME initial draw as the resident path (bit-for-bit), even
         # when the topics then live host-side for streaming
         topics = jax.random.randint(
@@ -1078,12 +1208,13 @@ class DistLDATrainer(_StreamedDistMixin):
         return self._step(*self._step_inputs, state)
 
     def run_fused(self, state: DistLDAState, n_iters: int):
-        """n_iters eval-free iterations in ONE dispatch (fused pipeline).
+        """n_iters eval-free iterations, the state's buffers donated.
 
-        lax.scan over the per-shard step with the state buffers donated:
-        the multi-device analogue of train/lda_step.run_fused — no host
-        sync, no per-iteration dispatch. Returns (state, stacked stats)
-        where each stats leaf has a leading (n_iters,) axis.
+        One program for every ``n_iters``: the one-iteration step is
+        dispatched ``n_iters`` times without a host sync between them (a
+        scan per length would compile again for each new ``fit(n)``).
+        Returns (state, stacked stats) where each stats leaf has a leading
+        (n_iters,) axis.
         """
         if chaos.armed():
             # host-level chaos surface for the traced _dist_step: the int()
@@ -1091,21 +1222,13 @@ class DistLDATrainer(_StreamedDistMixin):
             chaos.step_range(int(state.iteration), int(n_iters))
         if isinstance(state, DistStreamState):
             return self._stream_run(state, n_iters)
-        fn = self._scan_cache.get(n_iters)
-        if fn is None:
-            sm = self._sm_step
-            n_in = len(self._step_inputs)
-
-            def multi(*args):
-                inputs, st = args[:n_in], args[n_in]
-
-                def body(carry, _):
-                    return sm(*inputs, carry)
-                return jax.lax.scan(body, st, None, length=n_iters)
-
-            fn = jax.jit(multi, donate_argnums=(n_in,))
-            self._scan_cache[n_iters] = fn
-        return fn(*self._step_inputs, state)
+        rows = []
+        for _ in range(int(n_iters)):
+            state, stats = self._run_step(*self._step_inputs, state)
+            rows.append(stats)
+        return state, three_branch.ThreeBranchStats(*[
+            np.asarray([np.asarray(getattr(r, f)) for r in rows], np.float32)
+            for f in three_branch.ThreeBranchStats._fields])
 
     # -- elastic checkpointing ---------------------------------------------
     # Checkpoints store topics in GLOBAL token order (+ rng + iteration), so
@@ -1218,6 +1341,17 @@ class DistLDATrainer(_StreamedDistMixin):
             D, W, n_tokens=self.corpus.n_tokens,
             where=f"distributed chunk boundary (iteration "
                   f"{int(state.iteration)})")
+
+    def llpt(self, state) -> float:
+        """LLPT of the state. A resident dense state with whole topics on
+        each shard is scored on the chips (program ``jit__shard_llpt``):
+        each shard's tokens against its D rows and its W replica, one
+        psum. Any other state is gathered to the host first."""
+        if self._llpt is None or isinstance(state, DistStreamState):
+            D, W = self.gather_global(state)
+            return _host_llpt(self.corpus, self.cfg, D, W)
+        return float(self._llpt(self.word_ids, self.doc_ids, self.mask,
+                                state.D, state.W))
 
 
 # ---------------------------------------------------------------------------
@@ -1835,6 +1969,11 @@ class PSDistTrainer:
             nd = int(self.sc.docs_per_shard[s])
             D[self.sc.doc_map[s][:nd]] += D_sh[s][:nd]
         return D, ss.server.gather_global()
+
+    def llpt(self, ss: PSStreamState) -> float:
+        """LLPT of the committed counts, gathered to the host."""
+        D, W = self.gather_global(ss)
+        return _host_llpt(self.corpus, self.cfg, D, W)
 
     def state_nbytes(self, ss: PSStreamState) -> int:
         """Per-host live count bytes: this worker's D block plus the

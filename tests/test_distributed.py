@@ -60,9 +60,9 @@ def test_dist_converges_and_conserves_tokens():
 
 @pytest.mark.slow
 def test_run_fused_matches_stepwise():
-    """The scanned run_fused (donated state, stacked stats) is bit-identical
-    to calling step() the same number of times — the multi-device analogue
-    of tests/test_fused_step.py's scan-vs-stepwise pin."""
+    """run_fused (donated state, stacked stats) is bit-identical to calling
+    step() the same number of times — the multi-device analogue of
+    tests/test_fused_step.py's scan-vs-stepwise pin."""
     out = _run("""
     mesh = jax.make_mesh((4, 2), ("data", "model"))
     tr = DistLDATrainer(corpus, cfg, mesh, pad_multiple=256, _from_engine=True)
@@ -210,6 +210,175 @@ def test_token_balanced_sharding_with_dissection():
     print("OK", ll0, ll1)
     """)
     assert "OK" in out
+
+
+@pytest.mark.parametrize("balance", ["none", "tiles"])
+def test_host_counts_match_a_scatter(balance):
+    """The host count build (one histogram pass) gives the per-token
+    scatter's counts, every holder of a dissected document included."""
+    import numpy as np
+    from repro.lda.corpus import relabel_by_frequency, synthetic_lda_corpus
+    from repro.lda.distributed import _host_counts, shard_corpus
+    corpus, _ = relabel_by_frequency(synthetic_lda_corpus(
+        0, n_docs=80, n_words=100, n_topics=8, mean_doc_len=50))
+    sc = shard_corpus(corpus, 4, pad_multiple=256, balance=balance)
+    K = 16
+    t = np.random.default_rng(0).integers(0, K, sc.word_ids.shape)
+    Dg = np.zeros((corpus.n_docs, K), np.int64)
+    W = np.zeros((corpus.n_words, K), np.int32)
+    for s in range(sc.n_shards):
+        sel = sc.mask[s] > 0
+        np.add.at(Dg, (sc.doc_map[s][sc.doc_ids[s][sel]], t[s][sel]), 1)
+        np.add.at(W, (sc.word_ids[s][sel], t[s][sel]), 1)
+    D_got, W_got = _host_counts(sc, corpus, K, t)
+    assert W_got.dtype == np.int32 and np.array_equal(W_got, W)
+    for s in range(sc.n_shards):
+        nd = int(sc.docs_per_shard[s])
+        assert np.array_equal(D_got[s, :nd], Dg[sc.doc_map[s][:nd]])
+        assert not D_got[s, nd:].any()
+
+
+# ---------------------------------------------------------------------------
+# the data-parallel path draws the single backend's topics (4 forged devices)
+# ---------------------------------------------------------------------------
+
+_PRELUDE_DP = """
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import sys; sys.path.insert(0, "src")
+import numpy as np, jax, jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+from repro.core import three_branch
+from repro.lda.api import LDAEngine
+from repro.lda.corpus import synthetic_lda_corpus, relabel_by_frequency
+from repro.lda.model import LDAConfig
+from repro.runtime.compat import make_mesh
+
+corpus = synthetic_lda_corpus(0, n_docs=120, n_words=200, n_topics=8,
+                              mean_doc_len=60)
+corpus, _ = relabel_by_frequency(corpus)
+# a 64-token tile: survivor chunks of 64 slots on a shard's 2,048
+cfg = LDAConfig(n_topics=16, tile_size=64, eval_every=5)
+
+def engines():
+    single = LDAEngine(corpus, cfg, backend="single")
+    dist = LDAEngine(corpus, cfg, backend="distributed",
+                     mesh=make_mesh((4, 1), ("data", "model")),
+                     pad_multiple=256)
+    assert dist.trainer.draws_as_single
+    return single, dist
+
+def document_D(dist):
+    tr = dist.trainer
+    D_sh = np.asarray(dist.state.D)
+    D = np.zeros((corpus.n_docs, cfg.n_topics), D_sh.dtype)
+    for s in range(tr.sc.n_shards):
+        nd = int(tr.sc.docs_per_shard[s])
+        D[tr.sc.doc_map[s][:nd]] = D_sh[s][:nd]
+        assert not D_sh[s][nd:].any()           # pad rows hold nothing
+    return D
+"""
+
+
+def _run_dp(body: str):
+    proc = subprocess.run(
+        [sys.executable, "-c", _PRELUDE_DP + textwrap.dedent(body)],
+        capture_output=True, text=True, timeout=600, cwd=".")
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    assert "OK" in proc.stdout, proc.stdout[-2000:]
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("start", ["random", "converged"])
+def test_data_parallel_matches_single_bitwise(start):
+    """From one payload, three iterations on a (4, 1) mesh give the single
+    backend's topics, D and W bit for bit (and its next key): from random
+    topics every shard draws every token, from a converged state every
+    shard draws survivor chunks."""
+    _run_dp(f"""
+    single, dist = engines()
+    single.fit(40 if {start!r} == "converged" else 0)
+    dist.restore(single.host_payload())
+    hs, hd = single.fit(3), dist.fit(3)
+    ps, pd = single.host_payload(), dist.host_payload()
+    assert np.array_equal(ps["topics_global"], pd["topics_global"])
+    assert np.array_equal(ps["key"], pd["key"])
+    assert ps["iteration"] == pd["iteration"]
+    assert np.array_equal(np.asarray(single.state.D), document_D(dist))
+    assert np.array_equal(np.asarray(single.state.W),
+                          np.asarray(dist.state.W))
+    branch = 1.0 if {start!r} == "converged" else 0.0
+    for h in (hs, hd):
+        assert [s["phase2_compacted"] for s in h["stats"]] == [branch]
+    print("OK")
+    """)
+
+
+@pytest.mark.slow
+def test_data_parallel_llpt_matches_single():
+    """One seed trains one chain on either backend, and the LLPT the
+    distributed fit scores on the chips (per-shard terms, one psum)
+    matches the single backend's within 1e-6."""
+    _run_dp("""
+    single, dist = engines()
+    hs, hd = single.fit(6), dist.fit(6)
+    assert np.array_equal(single.host_payload()["topics_global"],
+                          dist.host_payload()["topics_global"])
+    assert hs["iteration"] == hd["iteration"] == [1, 5]
+    for a, b in zip(hs["llpt"], hd["llpt"]):
+        assert abs(a - b) <= 1e-6 * abs(a), (a, b)
+    # dissected documents hold their whole row on every holder, so a
+    # token-balanced state is scored on the chips too
+    from repro.lda.distributed import _host_llpt
+    tiles = LDAEngine(corpus, LDAConfig(n_topics=16, tile_size=64,
+                                        balance="tiles"),
+                      backend="distributed",
+                      mesh=make_mesh((4, 1), ("data", "model")),
+                      pad_multiple=256)
+    assert tiles.trainer.sc.shared_rows is not None
+    tiles.fit(2)
+    got = tiles.trainer.llpt(tiles.state)
+    want = _host_llpt(corpus, tiles.config,
+                      *tiles.trainer.gather_global(tiles.state))
+    assert abs(got - want) <= 1e-6 * abs(want), (got, want)
+    print("OK")
+    """)
+
+
+@pytest.mark.slow
+def test_data_parallel_fit_lowers_once():
+    """fit(1) loads every program the data-parallel path runs; a later
+    fit(3) lowers none, whatever its length."""
+    _run_dp("""
+    _, dist = engines()
+    first = dist.fit(1)["lowered"]
+    assert "jit__dp_step" in first and "jit__shard_llpt" in first, first
+    assert dist.fit(3)["lowered"] == []
+    assert dist.fit(7)["lowered"] == []
+    print("OK")
+    """)
+
+
+@pytest.mark.slow
+def test_shard_uniforms_are_the_global_draw():
+    """A shard's uniforms are the single backend's one vector (drawn at
+    its padded length) at the shard's global positions."""
+    _run_dp("""
+    _, dist = engines()
+    tr = dist.trainer
+    key = jax.random.split(jax.random.PRNGKey(2**31 + 7))[1]
+    shard_u = jax.jit(jax.shard_map(
+        lambda g: three_branch.uniforms_at(key, g[0])[None], mesh=tr.mesh,
+        in_specs=P(tr.data_axes), out_specs=P(tr.data_axes),
+        check_vma=False))
+    u = np.asarray(shard_u(tr.global_pos))
+    n_pad = -(-corpus.n_tokens // cfg.tile_size) * cfg.tile_size
+    full = np.asarray(jax.random.uniform(key, (n_pad,), dtype=jnp.float32))
+    for s in range(tr.sc.n_shards):
+        sel = tr.sc.mask[s] > 0
+        assert np.array_equal(u[s][sel], full[tr.sc.global_pos[s][sel]]), s
+    print("OK")
+    """)
 
 
 @pytest.mark.slow

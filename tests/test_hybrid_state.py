@@ -210,6 +210,10 @@ def test_checkpoint_payload_restores_into_either_format(small_corpus):
 
 @pytest.mark.slow
 def test_dist_hybrid_matches_dist_dense_bitwise():
+    """The packed and the dense distributed state run one chain on the
+    per-shard draw. The dense side is streamed: its resident data-parallel
+    path draws the single backend's topics instead (tests/
+    test_distributed.py)."""
     proc = subprocess.run([sys.executable, "-c", textwrap.dedent("""
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -224,16 +228,19 @@ def test_dist_hybrid_matches_dist_dense_bitwise():
                                       mean_doc_len=50)
         corpus, _ = relabel_by_frequency(corpus)
         mesh = make_mesh((4, 1), ("data", "model"))
-        trd = DistLDATrainer(corpus, LDAConfig(n_topics=16, tile_size=512),
+        trd = DistLDATrainer(corpus, LDAConfig(n_topics=16, tile_size=512,
+                                               corpus_residency="streamed",
+                                               stream_shards=3),
                              mesh, pad_multiple=256, _from_engine=True)
         trh = DistLDATrainer(corpus, LDAConfig(n_topics=16, tile_size=512,
                                                format="hybrid"),
                              mesh, pad_multiple=256, _from_engine=True)
         sd, sh = trd.init_state(), trh.init_state()
+        n_loc = trd.stream.n_loc
         for i in range(5):
-            sd, _ = trd.step(sd)
+            sd, _ = trd.run_fused(sd, 1)
             sh, _ = trh.step(sh)
-            assert np.array_equal(np.asarray(sd.topics),
+            assert np.array_equal(sd.host_topics[:, :n_loc],
                                   np.asarray(sh.topics)), i
         Dd, Wd = trd.gather_global(sd)
         Dh, Wh = trh.gather_global(sh)

@@ -325,8 +325,13 @@ def mk(staleness=0, n_owners=None, fmt="dense"):
                          _from_engine=True)
 
 def mk_rep(fmt="dense"):
+    # the replicated trainer on the per-shard draw the PS shares: its
+    # streamed residency (the resident dense data-parallel path draws the
+    # single backend's topics instead)
     return DistLDATrainer(corpus, LDAConfig(n_topics=K, seed=11,
-                                            format=fmt), mesh,
+                                            format=fmt,
+                                            corpus_residency="streamed",
+                                            stream_shards=3), mesh,
                           pad_multiple=64, _from_engine=True)
 """
 

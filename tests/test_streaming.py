@@ -365,12 +365,17 @@ def test_config_rejects_bad_streaming_knobs():
 # 6. distributed streaming (single real device; forged meshes are slow)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("fmt", ["dense", "hybrid"])
-def test_streamed_equals_resident_distributed(small_corpus, fmt):
+@pytest.mark.parametrize("fmt, balance", [("dense", "tiles"),
+                                          ("hybrid", "none")],
+                         ids=["dense", "hybrid"])
+def test_streamed_equals_resident_distributed(small_corpus, fmt, balance):
+    """Streamed == resident where both run the per-shard sweep and its
+    draw: the dense leg under balance='tiles', since the resident dense
+    data-parallel path draws the single backend's topics instead."""
     from repro.lda.distributed import DistLDATrainer, DistStreamState
     from repro.runtime.compat import make_mesh
     mesh = make_mesh((1, 1), ("data", "model"))
-    kw = dict(n_topics=16, tile_size=512, format=fmt)
+    kw = dict(n_topics=16, tile_size=512, format=fmt, balance=balance)
     tr_r = DistLDATrainer(small_corpus, LDAConfig(**kw), mesh,
                           pad_multiple=256, _from_engine=True)
     s_r, _ = tr_r.run_fused(tr_r.init_state(), 4)
