@@ -515,34 +515,46 @@ def _dist_step(word_ids, doc_ids, mask, state, *,
     return new_state, stats
 
 
-def _dp_step(word_ids, doc_ids, mask, gpos, state, *, cfg: LDAConfig,
-             data_axes: tuple[str, ...], plan: three_branch.Plan):
-    """One iteration of a data shard of the resident dense data-parallel
-    path (model axis 1, document chunking): the single backend's draw.
+def _dp_sample(word_ids, doc_ids, mask, gpos, topics, D, W_hat, key, *,
+               plan: three_branch.Plan, alpha: float):
+    """The draw of a data shard of the resident dense data-parallel path
+    (model axis 1, document chunking): the single backend's draw, and no
+    collective.
 
     The key advances as ``LDATrainer.step``'s does (``key, sub =
     split(key)``), and the shard's tokens take their values of the one
     ``uniform(sub, (N,))`` from their global positions ``gpos`` (scope
     ``lda.uniforms``). The single backend's sampler then draws them
     (``three_branch.sample_from_uniforms``, survivor compaction
-    included): each document is whole on one shard, so its D row is the
-    global row, and W is the global W, so every token draws the topic the
-    single backend draws. Pad slots draw too and count nowhere. The
-    counts move by ±1 deltas, W's through one all-reduce (``lda.w_psum``).
-    The stats are means over the real tokens of all shards, summed over
-    the mesh after the sampler's branch; the phase-2 slot share and the
-    branch taken are means over the shards.
+    included) against ``W_hat``, made outside this program from the
+    replicated W as on one chip: each document is whole on one shard, so
+    its D row is the global row, and W is the global W, so every token
+    draws the topic the single backend draws. Pad slots draw too and
+    count nowhere. Returns the new topics, the shard's stats (per-token
+    stats summed over its real tokens) and the next key.
     """
-    word_ids, doc_ids, mask, gpos = word_ids[0], doc_ids[0], mask[0], gpos[0]
-    topics, D, W = state.topics[0], state.D[0], state.W
-    key, sub = jax.random.split(state.key)
+    gpos = gpos[0]
+    fmask = mask[0].astype(jnp.float32)
+    key, sub = jax.random.split(key)
     with jax.named_scope("lda.uniforms"):
         u = three_branch.uniforms_at(sub, gpos)
-    fmask = mask.astype(jnp.float32)
     new_topics, st = three_branch.sample_from_uniforms(
-        u, plan, word_ids, doc_ids, topics, D,
-        esca.compute_w_hat(W, cfg.beta), alpha=cfg.alpha_,
-        token_mean=lambda v: jnp.sum(v * fmask))
+        u, plan, word_ids[0], doc_ids[0], topics[0], D[0], W_hat,
+        alpha=alpha, token_mean=lambda v: jnp.sum(v * fmask))
+    return (new_topics[None],
+            jax.tree.map(lambda v: jnp.asarray(v, jnp.float32)[None], st),
+            key)
+
+
+def _dp_update(word_ids, doc_ids, mask, topics, new_topics, D, W, st, key,
+               iteration, *, data_axes: tuple[str, ...]):
+    """The count update of a data shard after ``_dp_sample``: ±1 deltas
+    (scope ``lda.count_update``), W's through one all-reduce
+    (``lda.w_psum``). The stats become means over the real tokens of
+    all shards; the phase-2 slot share and the branch taken, means over
+    the shards. Returns the next state and the stats."""
+    word_ids, doc_ids, mask = word_ids[0], doc_ids[0], mask[0]
+    topics, new_topics, D = topics[0], new_topics[0], D[0]
     wgt = mask.astype(jnp.int32)
     with jax.named_scope("lda.count_update"):
         dW_local = jnp.zeros_like(W).at[word_ids, topics].add(-wgt) \
@@ -551,7 +563,8 @@ def _dp_step(word_ids, doc_ids, mask, gpos, state, *, cfg: LDAConfig,
                  .add(wgt)
     with jax.named_scope("lda.w_psum"):
         dW = jax.lax.psum(dW_local, data_axes)            # delta all-reduce
-    denom = jax.lax.psum(jnp.sum(fmask), data_axes)
+    st = jax.tree.map(lambda v: v[0], st)
+    denom = jax.lax.psum(jnp.sum(mask.astype(jnp.float32)), data_axes)
 
     def tokens(v):
         return jax.lax.psum(v, data_axes) / denom
@@ -565,7 +578,31 @@ def _dp_step(word_ids, doc_ids, mask, gpos, state, *, cfg: LDAConfig,
         frac_phase2_slots=jax.lax.pmean(st.frac_phase2_slots, data_axes),
         phase2_compacted=jax.lax.pmean(st.phase2_compacted, data_axes))
     return DistLDAState(topics=new_topics[None], D=D_new[None], W=W + dW,
-                        key=key, iteration=state.iteration + 1), stats
+                        key=key, iteration=iteration + 1), stats
+
+
+def dp_programs(mesh: Mesh, plan: three_branch.Plan, alpha: float):
+    """The data-parallel iteration's two programs over ``mesh``, as
+    shard_maps to jit: ``_dp_sample`` (word, doc, mask, position and
+    topic shards, D, Ŵ, key) and ``_dp_update`` (word, doc, mask, old and
+    new topic shards, D, W, the sampler's stats, key, iteration)."""
+    daxes = batch_axes(mesh)
+    tok = P(daxes)
+    st = DistLDAState(topics=tok, D=P(daxes, None, "model"),
+                      W=P(None, "model"), key=P(), iteration=P())
+    n_stats = len(three_branch.ThreeBranchStats._fields)
+    shard_stats = three_branch.ThreeBranchStats(*[tok] * n_stats)
+    sample = _shard_map(
+        functools.partial(_dp_sample, plan=plan, alpha=alpha), mesh=mesh,
+        in_specs=(tok,) * 5 + (st.D, st.W, st.key),
+        out_specs=(tok, shard_stats, st.key), check_vma=False)
+    update = _shard_map(
+        functools.partial(_dp_update, data_axes=daxes), mesh=mesh,
+        in_specs=(tok,) * 5 + (st.D, st.W, shard_stats, st.key,
+                               st.iteration),
+        out_specs=(st, three_branch.ThreeBranchStats(*[P()] * n_stats)),
+        check_vma=False)
+    return sample, update
 
 
 def _shard_llpt(word_ids, doc_ids, mask, D, W, *, cfg: LDAConfig,
@@ -1031,11 +1068,18 @@ class DistLDATrainer(_StreamedDistMixin):
                 overflow=P(), key=P(), iteration=P())
         stats_spec = three_branch.ThreeBranchStats(
             *[P()] * len(three_branch.ThreeBranchStats._fields))
+        from repro.train.lda_step import resolve_residency
+        self.residency, self.n_stream_shards = resolve_residency(
+            config, int(self.sc.word_ids.shape[1]))
         # the resident dense data-parallel path draws the single backend's
-        # topics (_dp_step); topic blocks, dissected documents and packed
-        # D rows keep the per-shard sweep and its own draw (_dist_step)
+        # topics (_dp_sample, _dp_update); topic blocks, dissected
+        # documents, packed D rows and streamed residency (out-of-core:
+        # token arrays stay HOST-side and each device streams its own
+        # sub-shard sequence, DESIGN.md SS10) keep the per-shard sweep and
+        # its own draw (_dist_step)
         self.draws_as_single = (self.layout is None and self.pm == 1
-                                and self.sc.shared_slot is None)
+                                and self.sc.shared_slot is None
+                                and self.residency != "streamed")
         step = functools.partial(
             _dist_step, cfg=config, data_axes=daxes, model_axis="model",
             n_words=corpus.n_words, m_local=self.sc.m_local, g=config.g,
@@ -1043,11 +1087,12 @@ class DistLDATrainer(_StreamedDistMixin):
         if self.draws_as_single:
             self.plan = three_branch.plan_for(
                 int(self.sc.word_ids.shape[1]), config)
-            self._sm_step = _shard_map(
-                functools.partial(_dp_step, cfg=config, data_axes=daxes,
-                                  plan=self.plan),
-                mesh=mesh, in_specs=(tok_spec,) * 4 + (self.state_specs,),
-                out_specs=(self.state_specs, stats_spec), check_vma=False)
+            sm_sample, sm_update = dp_programs(mesh, self.plan,
+                                               config.alpha_)
+            self._sample = jax.jit(sm_sample)
+            self._update = jax.jit(sm_update)
+            # run_fused's update: the new topics, D and W donated
+            self._run_update = jax.jit(sm_update, donate_argnums=(4, 5, 6))
         elif self.sc.shared_slot is not None:
             def step_shared(word_ids, doc_ids, mask, shared_slot,
                             shared_rows, state):
@@ -1065,7 +1110,8 @@ class DistLDATrainer(_StreamedDistMixin):
                 in_specs=(tok_spec, tok_spec, tok_spec, self.state_specs),
                 out_specs=(self.state_specs, stats_spec),
                 check_vma=False)
-        self._step = jax.jit(self._sm_step)
+        if not self.draws_as_single:
+            self._step = jax.jit(self._sm_step)
         self._llpt = None
         if self.layout is None and self.pm == 1:
             # every shard holds whole D rows (a dissected document's row
@@ -1076,15 +1122,8 @@ class DistLDATrainer(_StreamedDistMixin):
                 in_specs=(tok_spec,) * 3 + (P(daxes, None, None), P()),
                 out_specs=P(), check_vma=False))
 
-        from repro.train.lda_step import resolve_residency
-        self.residency, self.n_stream_shards = resolve_residency(
-            config, int(self.sc.word_ids.shape[1]))
         dev = NamedSharding(mesh, tok_spec)
         if self.residency == "streamed":
-            # out-of-core: token arrays stay HOST-side; each device
-            # streams its own sub-shard sequence (DESIGN.md SS10) with
-            # the per-shard sweep and its own draw
-            self.draws_as_single = False
             self._build_stream()
             self._step_inputs = None
             if self.sc.shared_rows is not None:
@@ -1111,9 +1150,10 @@ class DistLDATrainer(_StreamedDistMixin):
                                  self.shared_slot, self.shared_rows)
         else:
             self._step_inputs = (self.word_ids, self.doc_ids, self.mask)
-        # run_fused's step: the same program, the state's buffers donated
-        self._run_step = jax.jit(self._sm_step,
-                                 donate_argnums=(len(self._step_inputs),))
+        if not self.draws_as_single:
+            # run_fused's step: the same program, the state's buffers donated
+            self._run_step = jax.jit(self._sm_step,
+                                     donate_argnums=(len(self._step_inputs),))
 
     def _put(self, x, spec):
         return jax.device_put(jnp.asarray(x), NamedSharding(self.mesh, spec))
@@ -1205,7 +1245,20 @@ class DistLDATrainer(_StreamedDistMixin):
                 "a streamed distributed trainer advances by whole epochs "
                 "(every token sub-shard must stream through before the "
                 "counts apply): use run_fused(state, n_iters)")
+        if self.draws_as_single:
+            return self._dp_iteration(state, self._update)
         return self._step(*self._step_inputs, state)
+
+    def _dp_iteration(self, state: DistLDAState, update):
+        """One data-parallel iteration as the single backend splits it:
+        Ŵ from the replicated W, the shards' draw (``jit__dp_sample``),
+        then ``update`` (``jit__dp_update``: the counts, the W-delta
+        all-reduce and the stats), with no host sync between them."""
+        W_hat = esca.compute_w_hat(state.W, self.cfg.beta)
+        new_topics, st, key = self._sample(
+            *self._step_inputs, state.topics, state.D, W_hat, state.key)
+        return update(*self._step_inputs[:3], state.topics, new_topics,
+                      state.D, state.W, st, key, state.iteration)
 
     def run_fused(self, state: DistLDAState, n_iters: int):
         """n_iters eval-free iterations, the state's buffers donated.
@@ -1224,7 +1277,10 @@ class DistLDATrainer(_StreamedDistMixin):
             return self._stream_run(state, n_iters)
         rows = []
         for _ in range(int(n_iters)):
-            state, stats = self._run_step(*self._step_inputs, state)
+            if self.draws_as_single:
+                state, stats = self._dp_iteration(state, self._run_update)
+            else:
+                state, stats = self._run_step(*self._step_inputs, state)
             rows.append(stats)
         return state, three_branch.ThreeBranchStats(*[
             np.asarray([np.asarray(getattr(r, f)) for r in rows], np.float32)
