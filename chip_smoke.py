@@ -45,7 +45,8 @@ import numpy as np
 ROOT = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.join(ROOT, "src"))
 
-# NYTimes (paper §VI, benchmarks/_common.DATASETS) at K = 1,000
+# NYTimes (paper §VI; the "published" block of bench/configs/nytimes-k1k.json)
+# at K = 1,000
 N_WORDS = 101_636
 N_DOCS = 299_752
 N_TOKENS = 100_000_000

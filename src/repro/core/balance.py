@@ -192,7 +192,7 @@ def assign_token_shards(corpus: Corpus, n_shards: int,
 def load_imbalance(corpus: Corpus, scheme: str, n_units: int,
                    tile_size: int = 4096,
                    dissect_threshold: int = 10_000) -> dict:
-    """Max/mean load ratio for a scheduling scheme (benchmarks/fig15).
+    """Max/mean load ratio for a scheduling scheme.
 
     Schemes:
       block_per_word    -- SaberLDA-style: unit u processes words u, u+P, ...

@@ -1,21 +1,12 @@
-"""Divisibility-safe sharding helpers.
-
-NamedSharding requires every sharded dim to divide by the product of its
-mesh axes. The LM zoo has dims that don't always divide (GQA kv=8 heads on a
-model=16 axis, batch=1 on data=16, ...); these helpers assign an axis only
-when it divides, otherwise replicate — and expose the decision so the
-roofline can attribute the resulting collectives.
-"""
+"""Mesh-axis helpers shared by the distributed LDA backend and serving."""
 
 from __future__ import annotations
 
 from typing import Sequence
 
-import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh
 
-__all__ = ["safe_spec", "safe_sharding", "mesh_axis_size", "batch_axes",
-           "LogicalRules", "use_rules", "constrain", "current_rules"]
+__all__ = ["mesh_axis_size", "batch_axes"]
 
 
 def mesh_axis_size(mesh: Mesh, axes: str | Sequence[str] | None) -> int:
@@ -29,149 +20,6 @@ def mesh_axis_size(mesh: Mesh, axes: str | Sequence[str] | None) -> int:
     return size
 
 
-def safe_spec(mesh: Mesh, dims: Sequence[int],
-              wanted: Sequence[str | tuple[str, ...] | None]) -> P:
-    """PartitionSpec assigning each wanted axis only if the dim divides.
-
-    ``wanted[i]`` is the mesh axis (or axis tuple) desired for dim i, or
-    None to replicate. Non-dividing assignments degrade to replication.
-    """
-    assert len(dims) == len(wanted)
-    out: list = []
-    used: set = set()
-    for dim, want in zip(dims, wanted):
-        if want is None:
-            out.append(None)
-            continue
-        axes = (want,) if isinstance(want, str) else tuple(want)
-        axes = tuple(a for a in axes if a not in used)   # one use per axis
-        size = mesh_axis_size(mesh, axes)
-        if axes and size > 1 and dim % size == 0:
-            used.update(axes)
-            out.append(axes[0] if len(axes) == 1 else axes)
-        else:
-            # try a prefix of the axis tuple before giving up
-            for cut in range(len(axes) - 1, 0, -1):
-                sz = mesh_axis_size(mesh, axes[:cut])
-                if sz > 1 and dim % sz == 0:
-                    used.update(axes[:cut])
-                    out.append(axes[:cut])
-                    break
-            else:
-                out.append(None)
-    return P(*out)
-
-
-def safe_sharding(mesh: Mesh, dims: Sequence[int],
-                  wanted: Sequence[str | tuple[str, ...] | None]
-                  ) -> NamedSharding:
-    return NamedSharding(mesh, safe_spec(mesh, dims, wanted))
-
-
 def batch_axes(mesh: Mesh) -> tuple[str, ...]:
     """The data-parallel axes: ('pod','data') on multi-pod, ('data',) else."""
     return tuple(a for a in ("pod", "data") if a in mesh.shape)
-
-
-# ---------------------------------------------------------------------------
-# logical-axis activation sharding (t5x-style rules, divisibility-safe)
-# ---------------------------------------------------------------------------
-
-import contextlib
-import contextvars
-import dataclasses
-
-
-@dataclasses.dataclass(frozen=True)
-class LogicalRules:
-    """Maps logical activation axes → mesh axes.
-
-    policy "tp" (default): batch over data axes, heads/ffn/vocab/experts
-    over the tensor axis, seq_tp/kv_seq over the tensor axis (Megatron-SP +
-    distributed flash-decode; DESIGN.md §5).
-
-    policy "dp": the mesh's model axis is repurposed as extra data
-    parallelism — batch shards over ALL axes, nothing tensor-shards. The
-    right mapping for small models (≲2B) whose TP collectives would dwarf
-    their compute (EXPERIMENTS.md §Perf iteration 1).
-    """
-    mesh: Mesh
-    table: dict = None
-    policy: str = "tp"
-
-    def __post_init__(self):
-        if self.table is None:
-            if self.policy in ("dp", "fsdp", "ep"):
-                all_axes = batch_axes(self.mesh) + (
-                    ("model",) if "model" in self.mesh.shape else ())
-                d = {"batch": all_axes, "seq": None, "seq_tp": None,
-                     "kv_seq": None, "heads": None, "kv_heads": None,
-                     "ffn": None, "vocab": None,
-                     "experts": "model" if self.policy == "ep" else None,
-                     "embed": None, "state": None}
-            else:
-                d = {
-                    "batch": batch_axes(self.mesh),
-                    "seq": None,
-                    "seq_tp": "model",
-                    "kv_seq": "model",
-                    "heads": "model",
-                    "kv_heads": "model",
-                    "ffn": "model",
-                    "vocab": "model",
-                    "experts": "model",
-                    "embed": None,
-                    "state": None,
-                }
-            object.__setattr__(self, "table", d)
-
-    def spec(self, dims, logical) -> P:
-        wanted = [self.table.get(a) if a else None for a in logical]
-        return safe_spec(self.mesh, dims, wanted)
-
-
-_RULES: contextvars.ContextVar = contextvars.ContextVar(
-    "logical_rules", default=None)
-
-
-@contextlib.contextmanager
-def use_rules(rules: LogicalRules | None):
-    tok = _RULES.set(rules)
-    try:
-        yield
-    finally:
-        _RULES.reset(tok)
-
-
-def current_rules() -> LogicalRules | None:
-    return _RULES.get()
-
-
-def constrain(x: jax.Array, *logical: str | None) -> jax.Array:
-    """with_sharding_constraint by logical axis names; no-op w/o rules."""
-    rules = _RULES.get()
-    if rules is None:
-        return x
-    spec = rules.spec(x.shape, logical)
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(rules.mesh, spec))
-
-
-def constrain_alt(x: jax.Array, *alternatives) -> jax.Array:
-    """Constrain with the first/most-sharded of several logical mappings.
-
-    Used where the preferred axis may not divide (e.g. 56 attention heads on
-    a 16-way model axis): the fallback shards the sequence dim instead
-    (sequence-parallel attention) rather than silently replicating.
-    """
-    rules = _RULES.get()
-    if rules is None:
-        return x
-    best, best_score = None, -1
-    for logical in alternatives:
-        spec = rules.spec(x.shape, logical)
-        score = sum(e is not None for e in spec)
-        if score > best_score:
-            best, best_score = spec, score
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(rules.mesh, best))

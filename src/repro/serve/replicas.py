@@ -20,7 +20,7 @@ The dispatch itself is the serving-optimized variant of
     φ_w through the per-word alias tables (``core/mh.word_proposals``
     machinery, O(1) per token) instead of uniformly, which cuts the
     sweeps needed to reach the fold-in LLPT plateau from ~5 to ~2
-    (measured in benchmarks/serve_service.py).
+    (measured on XLA:CPU).
   * the sweep body is exactly the batch API's ESCA semantics: phase-1
     skip test from the frozen word stats, survivor compaction, the exact
     combined sweep over cond-guarded chunks, one doc-histogram rebuild.

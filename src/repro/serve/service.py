@@ -73,7 +73,7 @@ class ServeConfig:
     co-riders, measured from the moment the batcher picks up its first
     doc (NOT from submit time: an already-expired submit-time deadline
     would cut odd-sized batches, each a fresh jit signature). ``n_sweeps=2`` with ``warm_start=True`` is the
-    measured serving sweet spot (benchmarks/serve_service.py: within
+    measured serving sweet spot (on XLA:CPU: within
     ~0.005 bits/token of the 5-sweep batch plateau at ~3× the
     throughput). ``hot_words=None`` pins the full vocabulary (cache
     disabled in the accounting sense — every token is a hit) unless

@@ -1,4 +1,3 @@
-"""Training/serving substrate: param sharding rules, AdamW+ZeRO-1,
-accumulating train step, KV-cache serve step — plus the fused LDA iteration
-pipeline (lda_step.py: donated single-dispatch step, incremental delta count
-updates, sync-free scanned training stretches)."""
+"""The fused LDA iteration pipeline (lda_step.py: donated single-dispatch
+step, incremental delta count updates, sync-free scanned training
+stretches, streamed and disk-paged epochs)."""

@@ -108,8 +108,8 @@ __all__ = ["FusedState", "FusedPipeline", "HybridFusedPipeline",
 # Per-tile phase-2 working-set budget (capacity · K · 4 B): the CPU-cache /
 # VMEM analogue of the paper's shared-memory-sized blocks. Equal-token
 # tiles sized to keep their working set resident are what turns the
-# structural balance into measured throughput (benchmarks/fig15_balance.py:
-# 16384-token chunks run ~1.7× slower than 1024-token tiles at K=64).
+# structural balance into measured throughput (on XLA:CPU, 16384-token
+# chunks ran ~1.7× slower than 1024-token tiles at K=64).
 TILE_WORKING_SET_BYTES = 1 << 18
 
 

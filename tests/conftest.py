@@ -1,5 +1,6 @@
-"""Shared fixtures. NOTE: no XLA_FLAGS here — smoke tests and benches must
-see the single real CPU device; only launch/dryrun.py forges 512 devices."""
+"""Shared fixtures. NOTE: no XLA_FLAGS here — the suite sees the single
+real CPU device; tests that need a multi-device mesh forge one in a
+subprocess."""
 
 import pytest
 

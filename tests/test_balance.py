@@ -15,6 +15,8 @@ The load-bearing properties:
      engaged or cond-fallback.
   4. ``assign_token_shards``: every token assigned exactly once, loads
      balanced within the LPT bound, >threshold words dissected.
+  5. ``load_imbalance`` keeps the paper's Fig-15 order on a power-law
+     corpus, with token tiles the only scheme near an even load.
 """
 
 import numpy as np
@@ -302,3 +304,18 @@ def test_shard_corpus_tiles_metadata(skewed_corpus):
                 tok = (sc.doc_ids[s] == row) & (sc.mask[s] > 0)
                 if tok.any():
                     assert np.all(sc.shared_slot[s][tok] == j), (s, j, g)
+
+
+def test_load_imbalance_fig15_order():
+    """Kernel-lane max/mean load on a power-law corpus: each scheme of the
+    paper's Fig. 15 at most as imbalanced as the one before it, and token
+    tiles within 1.2 of even (256-token tiles, many more than the 80
+    lanes, so the round-robin measures scheduling, not quantization)."""
+    c, _ = relabel_by_frequency(zipf_corpus(0, n_docs=600, n_words=3000,
+                                            exponent=1.5, mean_doc_len=150))
+    imb = [balance.load_imbalance(c, s, n_units=80, tile_size=256,
+                                  dissect_threshold=10_000)["imbalance"]
+           for s in ("block_per_word", "dynamic", "dynamic+dissect",
+                     "token_tiles")]
+    assert imb == sorted(imb, reverse=True), imb
+    assert imb[-1] <= 1.2 < imb[-2], imb

@@ -10,6 +10,8 @@ The load-bearing properties:
   3. run_fused (lax.scan) == repeated fused_step == the trainer loop, and
      chunk capacity is a pure performance knob (any capacity, same bits).
   4. The maintained Ŵ column sum never drifts from W.sum(axis=0).
+  5. A scanned stretch is one dispatch: no host sync inside it, on the
+     dense, hybrid and warp pipelines.
 """
 
 import numpy as np
@@ -201,3 +203,26 @@ def test_fused_scan_captures_no_corpus_constants(small_corpus, extra):
     finally:
         jax.config.update("jax_captured_constants_warn_bytes", was)
     assert not [w for w in seen if "constants were captured" in str(w.message)]
+
+
+# ---------------------------------------------------------------------------
+# 6. a scanned stretch makes no host sync
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("extra", [{}, {"format": "hybrid"},
+                                   {"sampler": "warp"}])
+def test_scanned_stretch_makes_no_host_sync(small_corpus, extra):
+    """After one compiling stretch, ``run_fused(replan=False)`` runs under
+    ``transfer_guard("disallow")``: a scalar read of a device value
+    (``int``, ``float``, ``bool``) or an implicit host-to-device copy
+    inside the scanned region raises. On the CPU a whole-array
+    ``np.asarray`` is zero-copy and passes the guard."""
+    cfg = LDAConfig(n_topics=16, tile_size=512, fused=True, **extra)
+    tr = LDATrainer(small_corpus, cfg, _from_engine=True)
+    pipe = tr.fused_pipeline()
+    fs, _, _ = pipe.run_fused(pipe.from_lda_state(tr.init_state()), 3)
+    fs, _, _ = pipe.run_fused(fs, 3, replan=False)          # compiles
+    jax.block_until_ready(fs.topics)
+    with jax.transfer_guard("disallow"):
+        fs, _, _ = pipe.run_fused(fs, 3, replan=False)
+        jax.block_until_ready(fs.topics)
